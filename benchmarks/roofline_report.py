@@ -17,16 +17,13 @@ def load_reports(out_dir: str = "artifacts/dryrun") -> List[Dict]:
     from repro.configs import SHAPES, get_config
     from repro.roofline.analysis import model_flops_for
     for r in rows:
-        try:
-            mf = model_flops_for(get_config(r["arch"]), SHAPES[r["shape"]])
-            r["model_flops"] = mf
-            if r.get("hlo_flops_total"):
-                r["useful_fraction"] = mf / r["hlo_flops_total"]
-                crit = max(r["compute_s"], r["memory_s"], r["collective_s"])
-                r["roofline_fraction"] = (r["useful_fraction"]
-                                          * r["compute_s"] / crit if crit else 0.0)
-        except Exception:
-            pass
+        mf = model_flops_for(get_config(r["arch"]), SHAPES[r["shape"]])
+        r["model_flops"] = mf
+        if r.get("hlo_flops_total"):
+            r["useful_fraction"] = mf / r["hlo_flops_total"]
+            crit = max(r["compute_s"], r["memory_s"], r["collective_s"])
+            r["roofline_fraction"] = (r["useful_fraction"]
+                                      * r["compute_s"] / crit if crit else 0.0)
     return rows
 
 

@@ -287,15 +287,12 @@ def main() -> None:
 
     if not args.skip_roofline and want("roofline"):
         from benchmarks.roofline_report import summarize
-        try:
-            rows = summarize()
-            all_rows += rows
-            for r in rows[:80]:
-                extra = (f"frac={r['roofline_frac']}" if "roofline_frac" in r
-                         else r.get("note", ""))
-                print(f"roofline/{r['cell']},,dominant={r['dominant']} {extra}")
-        except Exception as e:  # sweep not run yet
-            print(f"roofline,,skipped ({e})")
+        rows = summarize()  # empty until the dry-run sweep has written reports
+        all_rows += rows
+        for r in rows[:80]:
+            extra = (f"frac={r['roofline_frac']}" if "roofline_frac" in r
+                     else r.get("note", ""))
+            print(f"roofline/{r['cell']},,dominant={r['dominant']} {extra}")
 
     out = pathlib.Path("artifacts")
     out.mkdir(exist_ok=True)

@@ -28,11 +28,12 @@ args.add_argument("--slo-us", type=float, default=250.0,
 args = args.parse_args()
 
 # ------------------------------------------ preemption / recovery (jax side)
-clean = serve(arch="rwkv6_1p6b", scale="smoke", batch=2, prompt_len=32,
-              tokens=16, snapshot_every=4)
-crashy = serve(arch="rwkv6_1p6b", scale="smoke", batch=2, prompt_len=32,
-               tokens=16, snapshot_every=4, crash_at=9)
+clean, _ = serve(arch="rwkv6_1p6b", scale="smoke", batch=2, prompt_len=32,
+                 tokens=16, snapshot_every=4)
+crashy, stats = serve(arch="rwkv6_1p6b", scale="smoke", batch=2, prompt_len=32,
+                      tokens=16, snapshot_every=4, crash_at=9)
 np.testing.assert_array_equal(clean, crashy)
+assert stats["restores"] == 1
 print(f"generated {clean.shape[1]} tokens × {clean.shape[0]} requests")
 print("preempted replica restored from the Erda page store: outputs identical")
 

@@ -40,6 +40,10 @@ def _leaf_key(tag: str, step: int, path: str, shard: int) -> int:
 MANIFEST_KEY = 0x3A5F00D  # fixed key: its 8-byte atomic flip IS the commit
 
 
+class WriterCrash(RuntimeError):
+    """The checkpoint writer died mid-save (injected by ``fail_after_shards``)."""
+
+
 class ErdaCheckpointManager:
     def __init__(self, store: Optional[ErdaStore] = None, *, tag: str = "ckpt",
                  shard_bytes: int = 4 << 20):
@@ -64,7 +68,7 @@ class ErdaCheckpointManager:
                       for i in range(0, len(blob), self.shard_bytes)] or [b""]
             for si, sh in enumerate(shards):
                 if fail_after_shards is not None and written >= fail_after_shards:
-                    raise RuntimeError("injected checkpoint-writer crash")
+                    raise WriterCrash("injected checkpoint-writer crash")
                 self.store.write(_leaf_key(self.tag, step, pstr, si), sh)
                 written += 1
             entries.append({"path": pstr, "shards": len(shards)})

@@ -15,10 +15,21 @@ def _resolve_dtype(name: str) -> np.dtype:
         return np.dtype(getattr(ml_dtypes, name))
 
 
+def _meta(dtype, shape) -> bytes:
+    return json.dumps({"dtype": np.dtype(dtype).name,
+                       "shape": [int(d) for d in shape]}).encode()
+
+
 def leaf_to_bytes(arr) -> bytes:
     a = np.asarray(arr)
-    meta = json.dumps({"dtype": a.dtype.name, "shape": list(a.shape)}).encode()
+    meta = _meta(a.dtype, a.shape)
     return struct.pack("<I", len(meta)) + meta + a.tobytes()
+
+
+def encoded_size(shape, dtype) -> int:
+    """Length of ``leaf_to_bytes`` of an array of this shape and dtype."""
+    return (4 + len(_meta(dtype, shape))
+            + int(np.prod(shape, dtype=np.int64)) * np.dtype(dtype).itemsize)
 
 
 def leaf_from_bytes(buf: bytes) -> np.ndarray:

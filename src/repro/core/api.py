@@ -11,6 +11,7 @@ from __future__ import annotations
 
 from typing import Callable, List, Optional, Sequence, Tuple
 
+from repro.core import layout
 from repro.core.baselines.read_after_write import ReadAfterWriteStore
 from repro.core.baselines.redo_logging import RedoLoggingStore
 from repro.core.client import ErdaClient
@@ -60,6 +61,11 @@ class ErdaStore:
     def maybe_clean(self) -> int:
         from repro.core.cleaning import sweep_server
         return sweep_server(self.server)
+
+    @property
+    def max_value_bytes(self) -> int:
+        """Largest value one record holds: records never span a segment."""
+        return self.server.cfg.segment_size - layout.record_size(0)
 
     @property
     def devs(self) -> List[NVMDevice]:
@@ -159,6 +165,11 @@ class ErdaClusterStore:
     @property
     def n_shards(self) -> int:
         return self.cluster.n_shards
+
+    @property
+    def max_value_bytes(self) -> int:
+        """Largest value one record holds: records never span a segment."""
+        return self.cluster.cfg.segment_size - layout.record_size(0)
 
     @property
     def devs(self) -> List[NVMDevice]:

@@ -9,7 +9,8 @@ dims stay multiples of 128 when head_dim is.
 Causal masking is per-element inside the diagonal block; fully-masked KV
 blocks are skipped with pl.when (no MXU work issued).
 
-Validated in interpret mode against ref.attention_ref over shape/dtype sweeps.
+Validated in interpret mode against ref.attention_ref over shape/dtype sweeps;
+compiled for TPU v5e at olmo_1b widths in tests/test_tpu_compile.py.
 """
 from __future__ import annotations
 
@@ -69,7 +70,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
 
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, block_q: int = 128,
-                           block_k: int = 128, interpret: bool = True) -> jax.Array:
+                           block_k: int = 128, interpret: bool = False) -> jax.Array:
     """q,k,v: (BH, S, hd) → (BH, S, hd).  Same-length self attention."""
     bh, s, hd = q.shape
     block_q = min(block_q, s)

@@ -1,4 +1,6 @@
 import os
+# compiles against 512 virtual CPU devices: never open an attached TPU
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = ("--xla_force_host_platform_device_count=512 "
                            + os.environ.get("XLA_FLAGS_EXTRA", ""))
 
@@ -9,7 +11,7 @@ memory/cost analysis, and emit the roofline terms.
     PYTHONPATH=src python -m repro.launch.dryrun --arch olmo_1b --shape train_4k \
         --mesh single --out artifacts/dryrun
 
-The 512-device env var above MUST precede any other import (jax locks the
+The CPU pin and the 512-device env var above MUST precede any other import (jax locks the
 device count at first backend init) — hence the unusual import order.
 """
 import argparse          # noqa: E402
@@ -25,10 +27,14 @@ from repro.configs import SHAPES, cell_applicable, get_config            # noqa:
 from repro.launch.mesh import make_production_mesh                       # noqa: E402
 from repro.models import get_model                                       # noqa: E402
 from repro.optim import AdamWConfig                                      # noqa: E402
-from repro.roofline.analysis import model_flops_for, roofline_terms      # noqa: E402
+from repro.roofline.analysis import (model_flops_for, peaks_for,       # noqa: E402
+                                     roofline_terms)
 from repro.sharding import MeshInfo, batch_spec, cache_specs, param_specs  # noqa: E402
 from repro.train import make_train_state_abstract, make_train_step       # noqa: E402
 
+
+# the chip the production meshes are made of (roofline peaks)
+DEVICE_KIND = "TPU v5 lite"
 
 # gradient-accumulation policy for cells whose single-shot activations are too
 # tight at 16 GB/chip (memory figures on the CPU backend are ~2× inflated by
@@ -141,7 +147,8 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str | None,
     chips = mesh.devices.size
     report = roofline_terms(arch=arch, shape=shape_name, mesh_name=mesh_kind,
                             chips=chips, cost=cost, hlo_text=hlo,
-                            model_flops=model_flops_for(cfg, shape))
+                            model_flops=model_flops_for(cfg, shape),
+                            device_kind=DEVICE_KIND)
     rec = report.to_json()
     rec.update(
         lower_s=round(t_lower, 1), compile_s=round(t_compile, 1),
@@ -234,11 +241,12 @@ def run_cell_fit(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
     report = roofline_terms(
         arch=arch, shape=shape_name, mesh_name=mesh_kind, chips=mf["chips"],
         cost={"flops": fit("flops"), "bytes accessed": fit("bytes")},
-        hlo_text="", model_flops=model_flops_for(cfg, shape))
+        hlo_text="", model_flops=model_flops_for(cfg, shape),
+        device_kind=DEVICE_KIND)
     # collective term fitted separately (fitted from the per-depth HLO parses)
     coll_fit = fit("coll")
     report.collective_bytes_per_chip = coll_fit
-    report.collective_s = coll_fit / 50e9
+    report.collective_s = coll_fit / peaks_for(DEVICE_KIND)["link_bw"]
     rec = report.to_json()
     rec.update(
         raw_scan_once={"flops": mf["flops"], "bytes": mf["bytes"], "coll": mf["coll"]},

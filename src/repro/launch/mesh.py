@@ -6,23 +6,12 @@ dryrun.py is allowed to force 512 host devices)."""
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
-    """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
+    """16×16 = 256 chips per pod; 2 pods = 512 chips when multi_pod.  Auto
+    axes: the sharding rules place activations with sharding constraints."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_test_mesh(data: int = 2, model: int = 2, pod: int = 0):
-    """Small mesh for CPU tests (requires xla_force_host_platform_device_count)."""
-    if pod:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
-
-
-# TPU v5e constants for the roofline model (per chip)
-PEAK_FLOPS_BF16 = 197e12        # FLOP/s
-HBM_BW = 819e9                  # B/s
-ICI_BW_PER_LINK = 50e9          # B/s per link
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

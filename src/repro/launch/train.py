@@ -18,9 +18,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import ErdaCheckpointManager
+from repro.checkpoint.erda_ckpt import WriterCrash
 from repro.configs import get_config
 from repro.configs.base import ShapeConfig
 from repro.data import SyntheticTokens
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import get_model
 from repro.optim import AdamWConfig, cosine_schedule
 from repro.train import make_train_step
@@ -83,7 +85,7 @@ def train(arch="olmo_1b", scale="smoke", steps=50, batch=8, seq=128,
                 kwargs["fail_after_shards"] = 3
             try:
                 mgr.save(s + 1, state, **kwargs)
-            except RuntimeError as e:
+            except WriterCrash as e:
                 print(f"[train] checkpoint writer crashed @ step {s+1}: {e}")
     return state, losses, mgr
 
@@ -99,6 +101,7 @@ def main():
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-4)
     args = ap.parse_args()
+    use_compile_cache()
     _, losses, _ = train(args.arch, args.scale, args.steps, args.batch,
                          args.seq, args.ckpt_every, args.resume, lr=args.lr)
     print(f"[train] done: first loss {losses[0]:.4f} → last {losses[-1]:.4f}")

@@ -79,7 +79,7 @@ def wkv_chunked(r, k, v, w, u, h0, chunk: int):
         # k'_j = k_j·exp(−lw_j) so r'_i·k'_j = exp(lw_{i−1} − lw_j)·r_i·k_j.
         # −lw_j grows with in-chunk position; clamp at 30 — the clamp only
         # bites when the true pair decay exp(lw_i−lw_j) is ≈ 0 anyway.
-        k_dec = ki * jnp.exp(jnp.clip(-lwi, a_max=30.0))
+        k_dec = ki * jnp.exp(jnp.clip(-lwi, max=30.0))
         # intra-chunk: scores[i,j] = Σ_d r'_i k'_j  for j<i (strict lower-tri)
         scores = jnp.einsum("bihd,bjhd->bhij", r_dec, k_dec)
         tri = jnp.tril(jnp.ones((ri.shape[1], ri.shape[1]), bool), k=-1)

@@ -23,6 +23,23 @@ _DTYPE_BYTES = {
     "s32": 4, "u32": 4, "f32": 4, "s64": 8, "u64": 8, "f64": 8, "c64": 8,
 }
 
+#: Per-chip peaks, keyed by ``jax.Device.device_kind``.  Source: Google Cloud
+#: TPU documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s,
+#: 1,600 Gbit/s of chip-to-chip interconnect over 4 links (50 GB/s each).
+PEAKS = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "link_bw": 50e9},
+}
+
+
+def peaks_for(device_kind: str) -> Dict[str, float]:
+    """The chip's peaks; an unknown device kind is an error, not a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no peaks for device kind {device_kind!r} "
+                         f"(known: {sorted(PEAKS)})") from None
+
+
 _COLLECTIVE_RE = re.compile(
     r"(\w[\w.\-]*)\s*=\s*((?:\([^)]*\)|\S+))\s+"
     r"(all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
@@ -129,9 +146,10 @@ class RooflineReport:
 
 def roofline_terms(*, arch: str, shape: str, mesh_name: str, chips: int,
                    cost: Dict[str, float], hlo_text: str, model_flops: float,
-                   peak_flops: float = 197e12, hbm_bw: float = 819e9,
-                   link_bw: float = 50e9) -> RooflineReport:
-    """cost = compiled.cost_analysis() of the PER-DEVICE partitioned module."""
+                   device_kind: str) -> RooflineReport:
+    """cost = compiled.cost_analysis() of the PER-DEVICE partitioned module;
+    ``device_kind`` names the chip whose peaks bound the terms."""
+    peaks = peaks_for(device_kind)
     flops_dev = float(cost.get("flops", 0.0))
     bytes_dev = float(cost.get("bytes accessed", 0.0))
     coll = collective_bytes_from_hlo(hlo_text)
@@ -144,9 +162,9 @@ def roofline_terms(*, arch: str, shape: str, mesh_name: str, chips: int,
         collective_bytes_per_chip=coll_dev,
         collective_breakdown={**coll, "counts": counts},
         model_flops=model_flops,
-        compute_s=flops_dev / peak_flops,
-        memory_s=bytes_dev / hbm_bw,
-        collective_s=coll_dev / link_bw,
+        compute_s=flops_dev / peaks["flops"],
+        memory_s=bytes_dev / peaks["hbm_bw"],
+        collective_s=coll_dev / peaks["link_bw"],
     )
 
 

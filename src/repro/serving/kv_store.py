@@ -21,14 +21,19 @@ from typing import List, Optional, Sequence
 import jax
 import numpy as np
 
-from repro.checkpoint.serialization import leaf_from_bytes, leaf_to_bytes
+from repro.checkpoint.serialization import (encoded_size, leaf_from_bytes,
+                                           leaf_to_bytes)
 from repro.core import ServerConfig, make_store
 from repro.core.hashtable import splitmix64
 
-#: per-shard geometry for the default serving cluster
-PAGE_SHARD_CONFIG = ServerConfig(device_size=256 << 20, table_capacity=1 << 14,
-                                 n_heads=4, region_size=16 << 20,
-                                 segment_size=4 << 20)
+#: per-shard geometry for the default serving cluster: the paper's 8 MiB
+#: segments, and room on each of the two shards for the ~5 snapshots of a
+#: full-width olmo_1b decode cache (batch 4, 256 slots: 128 MiB each) that
+#: one preempted 32-token run writes.  The device is zero-filled lazily, so
+#: the size costs host memory only as pages are written.
+PAGE_SHARD_CONFIG = ServerConfig(device_size=1 << 30, table_capacity=1 << 14,
+                                 n_heads=4, region_size=64 << 20,
+                                 segment_size=8 << 20)
 
 
 def _page_key(seq_id: int, name: str, idx: int) -> int:
@@ -44,6 +49,7 @@ class ErdaKVPageStore:
         self.store = store or make_store("erda-cluster", n_shards=n_shards,
                                          replication=replication,
                                          cfg=PAGE_SHARD_CONFIG)
+        self.counters = {"snapshots": 0, "snapshot_bytes": 0, "restores": 0}
 
     def put_page(self, seq_id: int, name: str, idx: int, array) -> None:
         self.store.write(_page_key(seq_id, name, idx), leaf_to_bytes(array))
@@ -66,23 +72,40 @@ class ErdaKVPageStore:
     # ------------------------------------------------- cache snapshot/restore
     def snapshot_cache(self, seq_id: int, cache) -> int:
         """Persist a whole decode cache pytree as numbered pages — one batched
-        multi_write (2 doorbells per shard), not one write per leaf."""
-        leaves = jax.tree_util.tree_flatten_with_path(cache)[0]
-        self.store.multi_write(
-            [(_page_key(seq_id, jax.tree_util.keystr(path), 0),
-              leaf_to_bytes(leaf)) for path, leaf in leaves])
-        return len(leaves)
+        multi_write (2 doorbells per shard), not one write per leaf.  A leaf
+        larger than one record is split into record-sized pages, since a
+        record never spans a log segment."""
+        page = self.store.max_value_bytes
+        items = []
+        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+            blob = leaf_to_bytes(leaf)
+            name = jax.tree_util.keystr(path)
+            items += [(_page_key(seq_id, name, i), blob[off : off + page])
+                      for i, off in enumerate(range(0, len(blob), page))]
+        self.store.multi_write(items)
+        self.counters["snapshots"] += 1
+        self.counters["snapshot_bytes"] += sum(len(v) for _, v in items)
+        return len(items)
 
     def restore_cache(self, seq_id: int, template):
+        """The cache pytree last snapshotted for ``seq_id``, as host arrays
+        shaped like ``template`` (arrays or ShapeDtypeStructs), or None.  One
+        batched multi_read fetches every page of every leaf."""
+        page = self.store.max_value_bytes
         leaves = jax.tree_util.tree_flatten_with_path(template)[0]
+        n_pages = [-(-encoded_size(leaf.shape, leaf.dtype) // page)
+                   for _, leaf in leaves]
         raws = self.store.multi_read(
-            [_page_key(seq_id, jax.tree_util.keystr(path), 0)
-             for path, _leaf in leaves])
-        out = []
-        for (path, leaf), raw in zip(leaves, raws):
-            if raw is None:
-                return None
-            out.append(leaf_from_bytes(raw).astype(np.asarray(leaf).dtype))
+            [_page_key(seq_id, jax.tree_util.keystr(path), i)
+             for (path, _), n in zip(leaves, n_pages) for i in range(n)])
+        if any(raw is None for raw in raws):
+            return None
+        out, at = [], 0
+        for (_, leaf), n in zip(leaves, n_pages):
+            blob = b"".join(raws[at : at + n])
+            at += n
+            out.append(leaf_from_bytes(blob).astype(leaf.dtype, copy=False))
+        self.counters["restores"] += 1
         return jax.tree_util.tree_unflatten(
             jax.tree_util.tree_structure(template), out)
 
@@ -104,5 +127,6 @@ class ErdaKVPageStore:
     def stats(self):
         """Backing-store op counters — includes the location cache's
         ``spec_hits`` / ``spec_misses`` / ``spec_invalidations``, i.e. how
-        often page re-fetches collapsed to one doorbell."""
-        return self.store.stats
+        often page re-fetches collapsed to one doorbell — plus this page
+        store's ``snapshots`` / ``snapshot_bytes`` / ``restores``."""
+        return {**self.store.stats, **self.counters}

@@ -26,7 +26,8 @@ SCRIPT = textwrap.dedent("""
     from repro.train import make_train_state_abstract, make_train_step
 
     arch = sys.argv[1]
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 3)
     info = MeshInfo(mesh)
     cfg = dataclasses.replace(get_config(arch).scaled_down(), d_model=64,
                               head_dim=16, n_heads=4, n_kv_heads=2 if arch != "whisper_small" else 4)
@@ -68,7 +69,8 @@ SCRIPT = textwrap.dedent("""
 def test_small_mesh_dryrun(arch):
     r = subprocess.run([sys.executable, "-c", SCRIPT, arch],
                        capture_output=True, text=True, timeout=600,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"})
+                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+                            "JAX_PLATFORMS": "cpu"})
     assert r.returncode == 0, r.stdout + r.stderr
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["train"] and out["decode"]

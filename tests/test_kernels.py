@@ -44,14 +44,14 @@ def test_crc32_kernel_vs_ref_blocks(n, w, block):
 def test_crc32_detects_any_single_bitflip():
     rng = np.random.default_rng(1)
     data = rng.integers(0, 2**32, size=(8, 16), dtype=np.uint32)
-    base = np.asarray(ops.crc32_batch(jnp.asarray(data)))
+    base = np.asarray(ops.crc32_batch(jnp.asarray(data), interpret=True))
     for trial in range(20):
         row = rng.integers(0, 8)
         word = rng.integers(0, 16)
         bit = rng.integers(0, 32)
         mutated = data.copy()
         mutated[row, word] ^= np.uint32(1 << bit)
-        out = np.asarray(ops.crc32_batch(jnp.asarray(mutated)))
+        out = np.asarray(ops.crc32_batch(jnp.asarray(mutated), interpret=True))
         assert out[row] != base[row]
         mask = np.ones(8, bool)
         mask[row] = False
@@ -62,7 +62,7 @@ def test_crc32_bytes_batch_matches_zlib_on_padded():
     bufs = [b"hello world!", b"erda-object-123", b"x" * 40]
     ln = max(len(b) for b in bufs)
     ln_pad = (ln + 3) & ~3
-    got = ops.crc32_bytes_batch(bufs)
+    got = ops.crc32_bytes_batch(bufs, interpret=True)
     for i, b in enumerate(bufs):
         padded = b + b"\x00" * (ln_pad - len(b))
         assert got[i] == zlib.crc32(padded) & 0xFFFFFFFF
@@ -101,7 +101,7 @@ def test_flash_attention_wrapper_heads():
     q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True)
+    got = ops.flash_attention(q, k, v, causal=True, interpret=True)
     assert got.shape == (B, S, H, hd)
     from repro.models.layers.attention import full_attention
     want = full_attention(q, k, v, causal=True)
@@ -120,6 +120,7 @@ def test_flash_matches_model_chunked_attention():
     q = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((B, S, H, hd)), jnp.float32)
-    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64)
+    got = ops.flash_attention(q, k, v, causal=True, block_q=64, block_k=64,
+                              interpret=True)
     want = chunked_attention(q, k, v, cfg, causal=True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=3e-5, atol=3e-5)
