@@ -1,0 +1,8 @@
+"""Share of the traced window in which no operation ran on the device, in
+percent (1 - busy union / window)."""
+
+
+def read(ctx):
+    if ctx.trace is None:
+        return None
+    return 100 * (1 - ctx.trace["busy_s"] / ctx.trace["window_s"])

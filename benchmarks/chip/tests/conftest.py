@@ -1,0 +1,7 @@
+import pathlib
+import sys
+
+HERE = pathlib.Path(__file__).resolve().parent
+sys.path.insert(0, str(HERE.parent))
+sys.path.insert(0, str(HERE))
+sys.path.insert(0, str(HERE.parents[2] / "src"))
