@@ -51,6 +51,7 @@ from __future__ import annotations
 import struct
 from typing import Callable, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core import layout
 from repro.core.hashtable import ENTRY_SIZE, H, STATE_VALID
 from repro.core.log import head_id_for_key
@@ -223,7 +224,7 @@ class ErdaClient:
         """CRC-verify + parse a fetched object; one size-miss re-read if the
         header claims more bytes than the speculative read covered."""
         self.transport.client_crc(len(buf))  # client-side verification cost
-        rec = layout.parse_record(memoryview_to_np(buf), 0)
+        rec = self._verify(buf)
         if not rec.ok:
             # maybe the object is just longer than our speculative read: check
             # the header's claimed size and re-read once (size-miss path)
@@ -233,10 +234,16 @@ class ErdaClient:
                 if claimed > len(buf) and claimed <= self.segment_size:
                     buf = self._os_read(off, claimed)
                     self.transport.client_crc(len(buf))
-                    rec = layout.parse_record(memoryview_to_np(buf), 0)
+                    rec = self._verify(buf)
         if rec.ok:
             self.size_cache[key] = rec.size
         return rec
+
+    @staticmethod
+    def _verify(buf: bytes) -> layout.RecordView:
+        """CRC-check and parse a fetched record."""
+        with obs.span("client.verify", nbytes=len(buf)):
+            return layout.parse_record(memoryview_to_np(buf), 0)
 
     def _read_object(self, key: int, off: int) -> layout.RecordView:
         guess = self.size_cache.get(key, self.INITIAL_READ)

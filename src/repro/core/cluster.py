@@ -48,6 +48,7 @@ from __future__ import annotations
 import bisect
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
+from repro import obs
 from repro.core.client import ErdaClient
 from repro.core.hashtable import splitmix64
 from repro.core.replication import ShardDownError, ShardGroup
@@ -252,40 +253,44 @@ class ErdaCluster:
         DES layer replays per-shard traces concurrently.  Keys in an
         in-flight migration slice take the per-key dual-read path (rare: one
         slice at a time)."""
-        rs = self.resharding
-        out: List[Optional[bytes]] = [None] * len(keys)
-        by_shard: Dict[int, List[int]] = {}
-        for i, key in enumerate(keys):
-            if rs is not None:
-                shard, s = rs.route(key)
-                if s is not None:
-                    out[i] = rs.read(key, s)
-                    continue
-            else:
-                shard = self.ring.shard_for(key)
-            by_shard.setdefault(shard, []).append(i)
-        for shard, idxs in by_shard.items():
-            vals = self.groups[shard].multi_read([keys[i] for i in idxs])
-            for i, v in zip(idxs, vals):
-                out[i] = v
-        return out
+        with obs.span("store.multi_read") as sp:
+            rs = self.resharding
+            out: List[Optional[bytes]] = [None] * len(keys)
+            by_shard: Dict[int, List[int]] = {}
+            for i, key in enumerate(keys):
+                if rs is not None:
+                    shard, s = rs.route(key)
+                    if s is not None:
+                        out[i] = rs.read(key, s)
+                        continue
+                else:
+                    shard = self.ring.shard_for(key)
+                by_shard.setdefault(shard, []).append(i)
+            for shard, idxs in by_shard.items():
+                vals = self.groups[shard].multi_read([keys[i] for i in idxs])
+                for i, v in zip(idxs, vals):
+                    out[i] = v
+            sp.set(nbytes=sum(len(v) for v in out if v is not None))
+            return out
 
     def multi_write(self, items: Sequence[Tuple[int, bytes]]) -> None:
         """Batched write across shards: per-shard sub-batches, each 2
         doorbells (metadata flips, fence, data writes) on that shard's QP."""
-        rs = self.resharding
-        by_shard: Dict[int, List[Tuple[int, bytes]]] = {}
-        for key, value in items:
-            if rs is not None:
-                shard, s = rs.route(key)
-                if s is not None:
-                    rs.write(key, value, s)
-                    continue
-            else:
-                shard = self.ring.shard_for(key)
-            by_shard.setdefault(shard, []).append((key, value))
-        for shard, shard_items in by_shard.items():
-            self.groups[shard].multi_write(shard_items)
+        with obs.span("store.multi_write",
+                      nbytes=sum(len(v) for _, v in items)):
+            rs = self.resharding
+            by_shard: Dict[int, List[Tuple[int, bytes]]] = {}
+            for key, value in items:
+                if rs is not None:
+                    shard, s = rs.route(key)
+                    if s is not None:
+                        rs.write(key, value, s)
+                        continue
+                else:
+                    shard = self.ring.shard_for(key)
+                by_shard.setdefault(shard, []).append((key, value))
+            for shard, shard_items in by_shard.items():
+                self.groups[shard].multi_write(shard_items)
 
     # ------------------------------------------------------- elastic membership
     def add_shard(self, shard_id: Optional[int] = None, *, run: bool = True,

@@ -25,6 +25,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
+
 FLAG_DELETE = 0x01
 HEADER_FMT = "<BIHI"  # flags, crc, key_len, val_len
 HEADER_SIZE = struct.calcsize(HEADER_FMT)  # 11
@@ -45,11 +47,14 @@ def record_crc(flags: int, key: bytes, value: bytes) -> int:
 
 
 def pack_record(key: int, value: Optional[bytes], *, delete: bool = False) -> bytes:
-    kb = key_bytes(key)
-    vb = b"" if (delete or value is None) else bytes(value)
-    flags = FLAG_DELETE if delete else 0
-    crc = record_crc(flags, kb, vb)
-    return struct.pack(HEADER_FMT, flags, crc, len(kb), len(vb)) + kb + vb
+    """The record a client writes (every write path packs here)."""
+    with obs.span("client.pack",
+                  nbytes=0 if (delete or value is None) else len(value)):
+        kb = key_bytes(key)
+        vb = b"" if (delete or value is None) else bytes(value)
+        flags = FLAG_DELETE if delete else 0
+        crc = record_crc(flags, kb, vb)
+        return struct.pack(HEADER_FMT, flags, crc, len(kb), len(vb)) + kb + vb
 
 
 def record_size(val_len: int, *, delete: bool = False) -> int:

@@ -13,6 +13,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable, Dict, FrozenSet, Optional, Set, Tuple
 
+from repro import obs
 from repro.core import layout
 from repro.core.hashtable import Entry, HopscotchTable
 from repro.core.log import Head, LogSpace
@@ -59,23 +60,24 @@ class ErdaServer:
         one-sided data write (paper Fig 7 order).  Returns (addr, record_size,
         word) — the freshly published hash-table word rides back in the same
         response so the writer can warm its location cache for free."""
-        head = self.log.head_for_key(key)
-        cleaner = self.cleaners.get(head.head_id)
-        if cleaner is not None:
-            return cleaner.client_write_addr(key, val_len, delete=delete)
-        size = layout.record_size(val_len, delete=delete)
-        addr = head.reserve(size)
-        entry = self.table.lookup(key)
-        if entry is None:
-            if delete:
-                raise KeyError(f"delete of missing key {key}")
-            self.table.insert(key, head.head_id, addr)
-            word = layout.pack_word(1, addr, layout.NULL_OFF)
-        else:
-            word = layout.flip_word(entry.word, addr)
-            self.table.write_word(entry.slot, word)
-        head.record_written(addr, key, size, delete)
-        return addr, size, word
+        with obs.span("server.write_req"):
+            head = self.log.head_for_key(key)
+            cleaner = self.cleaners.get(head.head_id)
+            if cleaner is not None:
+                return cleaner.client_write_addr(key, val_len, delete=delete)
+            size = layout.record_size(val_len, delete=delete)
+            addr = head.reserve(size)
+            entry = self.table.lookup(key)
+            if entry is None:
+                if delete:
+                    raise KeyError(f"delete of missing key {key}")
+                self.table.insert(key, head.head_id, addr)
+                word = layout.pack_word(1, addr, layout.NULL_OFF)
+            else:
+                word = layout.flip_word(entry.word, addr)
+                self.table.write_word(entry.slot, word)
+            head.record_written(addr, key, size, delete)
+            return addr, size, word
 
     # --------------------------------------------------------------- repair path
     def handle_repair(self, key: int, observed_word: int) -> None:

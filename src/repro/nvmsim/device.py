@@ -20,6 +20,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro import obs
+
 
 class TornWrite(Exception):
     """Raised when a fault injector tears a write; the prefix was persisted."""
@@ -120,21 +122,24 @@ class NVMDevice:
     # -------------------------------------------------------------- data path
     def write(self, addr: int, data) -> None:
         """Non-atomic write; may be torn by the fault injector (prefix persists)."""
-        buf = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else np.asarray(data, dtype=np.uint8)
-        n = buf.size
-        if addr < 0 or addr + n > self.size:
-            raise ValueError(f"write out of range: [{addr}, {addr + n}) size={self.size}")
-        torn = self.fault.check(n)
-        persist = n if torn is None else torn
-        old = self.mem[addr : addr + persist]
-        changed = old != buf[:persist]
-        self.stats.bytes_written += n  # logical traffic (what Table 1 counts)
-        self.stats.bytes_programmed += int(changed.sum())
-        self.stats.bits_programmed += int(_POPCOUNT[np.bitwise_xor(old, buf[:persist])].sum())
-        self.stats.write_ops += 1
-        self.mem[addr : addr + persist] = buf[:persist]
-        if torn is not None:
-            raise TornWrite(addr, n, persist)
+        with obs.span("nvm.write") as sp:
+            buf = np.frombuffer(bytes(data), dtype=np.uint8) if isinstance(data, (bytes, bytearray)) else np.asarray(data, dtype=np.uint8)
+            n = buf.size
+            sp.set(nbytes=n)
+            if addr < 0 or addr + n > self.size:
+                raise ValueError(f"write out of range: [{addr}, {addr + n}) size={self.size}")
+            torn = self.fault.check(n)
+            persist = n if torn is None else torn
+            old = self.mem[addr : addr + persist]
+            self.stats.bytes_written += n  # logical traffic (what Table 1 counts)
+            with obs.span("nvm.dcw", nbytes=persist):
+                changed = old != buf[:persist]
+                self.stats.bytes_programmed += int(changed.sum())
+                self.stats.bits_programmed += int(_POPCOUNT[np.bitwise_xor(old, buf[:persist])].sum())
+            self.stats.write_ops += 1
+            self.mem[addr : addr + persist] = buf[:persist]
+            if torn is not None:
+                raise TornWrite(addr, n, persist)
 
     def write_u64_atomic(self, addr: int, value: int) -> None:
         """8-byte failure-atomic store (the NVM atomicity unit, §2.2)."""
@@ -149,7 +154,6 @@ class NVMDevice:
         self.stats.write_ops += 1
         self.stats.atomic_ops += 1
         self.mem[addr : addr + 8] = buf  # never torn: hardware guarantee
-        np.frombuffer(self.mem.data, dtype=np.uint64)  # noop view sanity
 
     def read_u64(self, addr: int) -> int:
         self.stats.bytes_read += 8

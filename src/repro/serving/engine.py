@@ -14,6 +14,8 @@ from typing import Dict, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
+
 
 class ServeEngine:
     def __init__(self, model, params, *, page_store=None,
@@ -32,27 +34,42 @@ class ServeEngine:
         """Greedy decode; optionally 'crash' after `crash_at` tokens (state is
         then restored from the Erda page store and decoding continues)."""
         import jax.numpy as jnp
-        logits, cache = self._prefill(self.params, batch)
-        token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        out = [np.asarray(token)]
-        step = 0
-        while len(out) < n_tokens:
-            if self.snapshot_every and step % self.snapshot_every == 0:
-                self.pages.snapshot_cache(seq_id, cache)
-                self.pages.put_page(seq_id, "__tokens__", 0,
-                                    np.concatenate(out, axis=1))
-            if crash_at is not None and step == crash_at:
-                cache = self._recover(seq_id, cache)
-                toks = self.pages.get_page(seq_id, "__tokens__", 0)
-                out = [toks[:, i : i + 1] for i in range(toks.shape[1])]
-                crash_at = None
-                token = jnp.asarray(out[-1])
-                continue
-            logits, cache = self._decode(self.params, cache, token)
+        with obs.span("serve.generate", seq_id=seq_id):
+            with obs.span("serve.prefill", seq_id=seq_id):
+                logits, cache = self._prefill(self.params, batch)
+            token, host = self._pick(logits, seq_id)
+            out = [host]
+            step = 0
+            while len(out) < n_tokens:
+                if self.snapshot_every and step % self.snapshot_every == 0:
+                    with obs.span("serve.snapshot", seq_id=seq_id):
+                        self.pages.snapshot_cache(seq_id, cache)
+                        self.pages.put_page(seq_id, "__tokens__", 0,
+                                            np.concatenate(out, axis=1))
+                if crash_at is not None and step == crash_at:
+                    with obs.span("serve.recover", seq_id=seq_id):
+                        cache = self._recover(seq_id, cache)
+                        toks = self.pages.get_page(seq_id, "__tokens__", 0)
+                    out = [toks[:, i : i + 1] for i in range(toks.shape[1])]
+                    crash_at = None
+                    token = jnp.asarray(out[-1])
+                    continue
+                with obs.span("serve.step", seq_id=seq_id):
+                    logits, cache = self._decode(self.params, cache, token)
+                token, host = self._pick(logits, seq_id)
+                out.append(host)
+                step += 1
+            return np.concatenate(out, axis=1)
+
+    @staticmethod
+    def _pick(logits, seq_id: int):
+        """The greedy token on the device, and its copy on the host: the
+        copy waits for the device to finish the step that made it."""
+        import jax.numpy as jnp
+        with obs.span("serve.pick", seq_id=seq_id):
             token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(np.asarray(token))
-            step += 1
-        return np.concatenate(out, axis=1)
+        with obs.span("serve.sync", seq_id=seq_id):
+            return token, np.asarray(token)
 
     def _recover(self, seq_id: int, template):
         restored = self.pages.restore_cache(seq_id, template)

@@ -21,6 +21,7 @@ from typing import List, Optional, Sequence
 import jax
 import numpy as np
 
+from repro import obs
 from repro.checkpoint.serialization import (encoded_size, leaf_from_bytes,
                                            leaf_to_bytes)
 from repro.core import ServerConfig, make_store
@@ -75,39 +76,53 @@ class ErdaKVPageStore:
         multi_write (2 doorbells per shard), not one write per leaf.  A leaf
         larger than one record is split into record-sized pages, since a
         record never spans a log segment."""
-        page = self.store.max_value_bytes
-        items = []
-        for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-            blob = leaf_to_bytes(leaf)
-            name = jax.tree_util.keystr(path)
-            items += [(_page_key(seq_id, name, i), blob[off : off + page])
-                      for i, off in enumerate(range(0, len(blob), page))]
-        self.store.multi_write(items)
-        self.counters["snapshots"] += 1
-        self.counters["snapshot_bytes"] += sum(len(v) for _, v in items)
-        return len(items)
+        with obs.span("pages.snapshot", seq_id=seq_id) as sp:
+            page = self.store.max_value_bytes
+            items = []
+            for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
+                with obs.span("pages.to_host") as th:
+                    host = np.asarray(leaf)
+                    th.set(nbytes=host.nbytes)
+                with obs.span("pages.encode", nbytes=host.nbytes):
+                    blob = leaf_to_bytes(host)
+                    name = jax.tree_util.keystr(path)
+                    items += [(_page_key(seq_id, name, i),
+                               blob[off : off + page])
+                              for i, off in enumerate(range(0, len(blob),
+                                                            page))]
+            self.store.multi_write(items)
+            nbytes = sum(len(v) for _, v in items)
+            self.counters["snapshots"] += 1
+            self.counters["snapshot_bytes"] += nbytes
+            sp.set(nbytes=nbytes)
+            return len(items)
 
     def restore_cache(self, seq_id: int, template):
         """The cache pytree last snapshotted for ``seq_id``, as host arrays
         shaped like ``template`` (arrays or ShapeDtypeStructs), or None.  One
         batched multi_read fetches every page of every leaf."""
-        page = self.store.max_value_bytes
-        leaves = jax.tree_util.tree_flatten_with_path(template)[0]
-        n_pages = [-(-encoded_size(leaf.shape, leaf.dtype) // page)
-                   for _, leaf in leaves]
-        raws = self.store.multi_read(
-            [_page_key(seq_id, jax.tree_util.keystr(path), i)
-             for (path, _), n in zip(leaves, n_pages) for i in range(n)])
-        if any(raw is None for raw in raws):
-            return None
-        out, at = [], 0
-        for (_, leaf), n in zip(leaves, n_pages):
-            blob = b"".join(raws[at : at + n])
-            at += n
-            out.append(leaf_from_bytes(blob).astype(leaf.dtype, copy=False))
-        self.counters["restores"] += 1
-        return jax.tree_util.tree_unflatten(
-            jax.tree_util.tree_structure(template), out)
+        with obs.span("pages.restore", seq_id=seq_id) as sp:
+            page = self.store.max_value_bytes
+            leaves = jax.tree_util.tree_flatten_with_path(template)[0]
+            n_pages = [-(-encoded_size(leaf.shape, leaf.dtype) // page)
+                       for _, leaf in leaves]
+            raws = self.store.multi_read(
+                [_page_key(seq_id, jax.tree_util.keystr(path), i)
+                 for (path, _), n in zip(leaves, n_pages) for i in range(n)])
+            if any(raw is None for raw in raws):
+                return None
+            out, at = [], 0
+            for (_, leaf), n in zip(leaves, n_pages):
+                with obs.span("pages.decode") as dec:
+                    blob = b"".join(raws[at : at + n])
+                    at += n
+                    out.append(leaf_from_bytes(blob).astype(leaf.dtype,
+                                                            copy=False))
+                    dec.set(nbytes=len(blob))
+            self.counters["restores"] += 1
+            sp.set(nbytes=sum(len(raw) for raw in raws))
+            return jax.tree_util.tree_unflatten(
+                jax.tree_util.tree_structure(template), out)
 
     def compact(self) -> None:
         """Page eviction/compaction = the paper's lock-free log cleaning,
