@@ -8,7 +8,8 @@ just derived.  The device models:
   * byte-addressable load/store over a flat address space,
   * the 8-byte failure-atomicity unit of the NVM memory bus (``write_u64_atomic``),
   * DCW (data-comparison write [31]) accounting: bits that do not change are not
-    programmed, which is why the flip-bit metadata update is cheap,
+    programmed, which is why the flip-bit metadata update is cheap.  The bits
+    are counted by a word popcount over old XOR new, in cache-sized chunks,
   * torn writes: a crash during a (non-atomic) write may persist an arbitrary
     prefix of the data — this is the failure Erda's CRC detects,
   * an extra write latency (default 150 ns, as in the paper) for latency models.
@@ -84,7 +85,31 @@ class NVMStats:
         )
 
 
-_POPCOUNT = np.unpackbits(np.arange(256, dtype=np.uint8)[:, None], axis=1).sum(1)
+# Bytes XORed per pass when a long write is DCW-counted: small enough that the
+# device's scratch buffer stays in cache.
+DCW_CHUNK = 1 << 18
+
+
+def _dcw_counts(old: np.ndarray, new: np.ndarray, scratch: np.ndarray) -> tuple[int, int]:
+    """(bytes, bits) that differ between ``old`` and ``new``: what DCW programs.
+
+    A write of at most one chunk is counted in a single pass over the uint8
+    XOR; a longer one XORs chunk by chunk into ``scratch`` (nothing allocated
+    per write) and popcounts whole 64-bit words, then the ragged tail.
+    """
+    n = old.size
+    if n <= scratch.size:
+        x = np.bitwise_xor(old, new)
+        return int(np.count_nonzero(x)), int(np.bitwise_count(x).sum())
+    nbytes = nbits = 0
+    for lo in range(0, n, scratch.size):
+        hi = min(lo + scratch.size, n)
+        x = np.bitwise_xor(old[lo:hi], new[lo:hi], out=scratch[: hi - lo])
+        nbytes += np.count_nonzero(x)
+        m = x.size & ~7
+        nbits += np.bitwise_count(x[:m].view(np.uint64)).sum(dtype=np.int64)
+        nbits += np.bitwise_count(x[m:]).sum(dtype=np.int64)
+    return int(nbytes), int(nbits)
 
 
 class NVMDevice:
@@ -106,6 +131,9 @@ class NVMDevice:
         self.write_bandwidth_gbps = write_bandwidth_gbps
         self.read_bandwidth_gbps = read_bandwidth_gbps
         self._alloc_ptr = 0
+        # DCW's XOR buffer for long writes, reused by every write.  Safe to
+        # share: nothing writes to one device from two threads.
+        self._dcw_scratch = np.empty(DCW_CHUNK, dtype=np.uint8)
 
     # ------------------------------------------------------------- allocation
     def alloc(self, nbytes: int, align: int = 8) -> int:
@@ -133,9 +161,9 @@ class NVMDevice:
             old = self.mem[addr : addr + persist]
             self.stats.bytes_written += n  # logical traffic (what Table 1 counts)
             with obs.span("nvm.dcw", nbytes=persist):
-                changed = old != buf[:persist]
-                self.stats.bytes_programmed += int(changed.sum())
-                self.stats.bits_programmed += int(_POPCOUNT[np.bitwise_xor(old, buf[:persist])].sum())
+                nbytes, nbits = _dcw_counts(old, buf[:persist], self._dcw_scratch)
+                self.stats.bytes_programmed += nbytes
+                self.stats.bits_programmed += nbits
             self.stats.write_ops += 1
             self.mem[addr : addr + persist] = buf[:persist]
             if torn is not None:
@@ -146,11 +174,10 @@ class NVMDevice:
         if addr % 8 != 0:
             raise ValueError("atomic u64 store must be 8-byte aligned")
         buf = np.frombuffer(np.uint64(value).tobytes(), dtype=np.uint8)
-        old = self.mem[addr : addr + 8]
-        changed = old != buf
+        nbytes, nbits = _dcw_counts(self.mem[addr : addr + 8], buf, self._dcw_scratch)
         self.stats.bytes_written += 8
-        self.stats.bytes_programmed += int(changed.sum())
-        self.stats.bits_programmed += int(_POPCOUNT[np.bitwise_xor(old, buf)].sum())
+        self.stats.bytes_programmed += nbytes
+        self.stats.bits_programmed += nbits
         self.stats.write_ops += 1
         self.stats.atomic_ops += 1
         self.mem[addr : addr + 8] = buf  # never torn: hardware guarantee

@@ -11,10 +11,12 @@ paper counts only the 5 programmed bytes; we assert the DCW-programmed bytes
 separately).  The measured formulas therefore shift by a small constant while
 preserving the paper's headline: update writes are ≈50 % of redo logging's.
 """
+import numpy as np
 import pytest
 
 from repro.core import make_store
 from repro.core.layout import HEADER_SIZE, KEY_BYTES
+from repro.nvmsim.device import DCW_CHUNK, NVMDevice, TornWrite
 
 
 def measure(store, op, key, value=None):
@@ -107,3 +109,56 @@ def test_dcw_programmed_bytes_below_logical():
     s.write(1, b"c" * 64)
     d = s.dev.stats.delta(before)
     assert d.bytes_programmed <= d.bytes_written
+
+
+@pytest.mark.parametrize("kind, addr, n, prefill", [
+    pytest.param("write", 0, 0, False, id="len0"),
+    pytest.param("write", 0, 1, False, id="len1"),
+    pytest.param("write", 0, 7, False, id="len7"),
+    pytest.param("write", 0, 8, False, id="len8"),
+    pytest.param("write", 0, 9, False, id="len9"),
+    pytest.param("write", 0, DCW_CHUNK - 1, False, id="chunk-1"),
+    pytest.param("write", 0, DCW_CHUNK, False, id="chunk"),
+    pytest.param("write", 0, DCW_CHUNK + 1, False, id="chunk+1"),
+    pytest.param("write", 0, 3 * DCW_CHUNK + 5, False, id="chunks_ragged"),
+    pytest.param("write", 3, 2 * DCW_CHUNK + 13, False, id="unaligned_addr"),
+    pytest.param("write", 5, 9, True, id="overwrite_short"),
+    pytest.param("write", 8, 3 * DCW_CHUNK + 5, True, id="overwrite_chunks"),
+    pytest.param("torn", 0, 9, True, id="torn_short"),
+    pytest.param("torn", 3, 3 * DCW_CHUNK + 5, True, id="torn_chunks"),
+    pytest.param("atomic", 16, 8, False, id="u64_atomic"),
+    pytest.param("atomic", 16, 8, True, id="u64_atomic_overwrite"),
+])
+def test_dcw_counts_match_reference(kind, addr, n, prefill):
+    """bytes_programmed and bits_programmed equal a plain reference for every
+    write: only the persisted prefix of a torn write is counted."""
+    rng = np.random.default_rng(addr * 1_000_003 + n)
+    dev = NVMDevice(addr + n + 64)
+    if prefill:
+        dev.mem[:] = rng.integers(0, 256, dev.size, dtype=np.uint8)
+    old = dev.mem[addr : addr + n].copy()
+    # flip ~1 bit in 8, so some bytes stay equal and others change by 1-8 bits
+    flips = rng.integers(0, 256, (3, n), dtype=np.uint8)
+    new = old ^ (flips[0] & flips[1] & flips[2])
+    before = dev.stats.snapshot()
+    if kind == "atomic":
+        dev.write_u64_atomic(addr, int(new.view(np.uint64)[0]))
+        persist = n
+    elif kind == "torn":
+        dev.fault.arm(fraction=0.5)
+        with pytest.raises(TornWrite) as torn:
+            dev.write(addr, new)
+        persist = torn.value.persisted
+        assert persist == n // 2
+    else:
+        dev.write(addr, new)
+        persist = n
+    d = dev.stats.delta(before)
+    o, w = old[:persist], new[:persist]
+    assert d.bytes_programmed == int((o != w).sum())
+    assert d.bits_programmed == int(np.unpackbits(np.bitwise_xor(o, w)).sum())
+    assert d.bytes_written == n
+    assert d.write_ops == 1
+    assert d.atomic_ops == (kind == "atomic")
+    assert np.array_equal(dev.mem[addr : addr + persist], w)
+    assert np.array_equal(dev.mem[addr + persist : addr + n], old[persist:])
