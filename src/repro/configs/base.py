@@ -41,8 +41,14 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_head_dim: int = 64
     ssm_chunk: int = 128
-    # hybrid (zamba2): one SHARED attention block applied every k ssm layers
-    shared_attn_every: int = 0
+    ssm_groups: int = 1          # B/C groups; head n reads group n // (heads/groups)
+    ssm_conv_bias: bool = False
+    # hybrid (zamba2): the layers whose Mamba2 input also takes a call of a
+    # weight-shared attention+MLP block; call j uses block j % n_mem_blocks,
+    # its own MLP adapter (rank adapter_rank, 0 = none) and output projection
+    hybrid_layer_ids: Tuple[int, ...] = ()
+    n_mem_blocks: int = 1
+    adapter_rank: int = 0
     # rwkv6
     rwkv_chunk: int = 64
     # enc-dec (whisper)
@@ -63,6 +69,11 @@ class ModelConfig:
                                  # once); state recurrences (ssm/rwkv) stay
                                  # scanned — <3%% of their layer FLOPs
 
+    def __post_init__(self):
+        # a configuration file gives the ids as a list; keep the config hashable
+        object.__setattr__(self, "hybrid_layer_ids",
+                           tuple(self.hybrid_layer_ids))
+
     # ------------------------------------------------------------------ utils
     @property
     def q_dim(self) -> int:
@@ -80,9 +91,16 @@ class ModelConfig:
     def ssm_heads(self) -> int:
         return self.d_inner // self.ssm_head_dim
 
+    @property
+    def hybrid_ids(self) -> Tuple[int, ...]:
+        """The hybrid layers present at this depth."""
+        return tuple(i for i in self.hybrid_layer_ids if i < self.n_layers)
+
     def scaled_down(self) -> "ModelConfig":
         """Reduced same-family config for CPU smoke tests."""
-        n_layers = min(self.n_layers, 4 if self.shared_attn_every == 0 else self.shared_attn_every * 2)
+        n_layers = min(self.n_layers, 4)
+        if len(self.hybrid_layer_ids) >= 2:  # two calls: both blocks of two
+            n_layers = min(self.n_layers, self.hybrid_layer_ids[1] + 1)
         lpg = self.local_per_global
         if lpg:
             n_layers = lpg + 1  # one full local:global group
@@ -101,7 +119,6 @@ class ModelConfig:
             ssm_state=min(self.ssm_state, 16) if self.ssm_state else 0,
             ssm_chunk=16,
             rwkv_chunk=16,
-            shared_attn_every=min(self.shared_attn_every, 2) if self.shared_attn_every else 0,
             encoder_layers=min(self.encoder_layers, 2),
             encoder_seq=min(self.encoder_seq, 32) if self.encoder_seq else 0,
             n_patches=min(self.n_patches, 8) if self.n_patches else 0,
@@ -126,11 +143,16 @@ class ModelConfig:
         if self.n_experts:
             mlp = mlp * self.n_experts + d * self.n_experts
         per = attn + mlp
-        if self.family == "hybrid":
-            di, ds, nh = self.d_inner, self.ssm_state, self.ssm_heads
-            ssm_per = d * (2 * di + 2 * ds + nh) + di * d + di * self.ssm_conv
-            n_sites = self.n_layers // max(1, self.shared_attn_every)
-            return emb + L * ssm_per + (attn + 3 * d * f)  # one shared block
+        if self.family == "hybrid":  # exact: every leaf of models/hybrid.py
+            di, nh = self.d_inner, self.ssm_heads
+            conv = di + 2 * self.ssm_groups * self.ssm_state
+            mamba = (d * (di + conv + nh) + self.ssm_conv * conv
+                     + conv * self.ssm_conv_bias + 3 * nh + di + di * d + d)
+            shared = (2 * d * self.q_dim + 4 * d * self.kv_dim
+                      + self.q_dim * d + 3 * d * f + 3 * d)
+            call = d * d + self.adapter_rank * (d + 2 * f)
+            return (emb + d + L * mamba + self.n_mem_blocks * shared
+                    + len(self.hybrid_ids) * call)
         if self.family == "encdec":
             cross = per  # decoder layers add cross-attention
             return emb + (self.encoder_layers + L) * per + L * attn

@@ -1,6 +1,6 @@
-"""zamba2-1.2b [hybrid]: 38 Mamba2 layers (d=2048, ssm_state=64) + a SHARED
-attention+MLP block (32H, kv=32, d_ff=8192) applied every 6 ssm layers.
-[arXiv:2411.15242]"""
+"""zamba2-1.2b [hybrid]: 38 Mamba2 layers (d=2048, ssm_state=64); every 6th
+layer's Mamba2 input also takes a call of ONE shared attention+MLP block
+(32H, kv=32, d_ff=8192) over [hidden; embedding].  [arXiv:2411.15242]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
@@ -8,5 +8,5 @@ CONFIG = ModelConfig(
     n_layers=38, d_model=2048, n_heads=32, n_kv_heads=32, head_dim=64,
     d_ff=8192, vocab_size=32_000,
     ssm_state=64, ssm_expand=2, ssm_head_dim=64,
-    shared_attn_every=6,
+    hybrid_layer_ids=(6, 12, 18, 24, 30, 36),
 )

@@ -50,9 +50,11 @@ def depth_points(cfg):
     if cfg.family == "encdec":
         return ({"n_layers": 1, "encoder_layers": 1}, 1), \
                ({"n_layers": 2, "encoder_layers": 2}, 2), cfg.n_layers
-    if cfg.attn_pattern == "local_global" or cfg.family == "hybrid":
-        g = (cfg.local_per_global + 1) if cfg.attn_pattern == "local_global" \
-            else cfg.shared_attn_every
+    if cfg.family == "hybrid":  # through the first, then the second call
+        a, b = (i + 1 for i in cfg.hybrid_layer_ids[:2])
+        return ({"n_layers": a}, a), ({"n_layers": b}, b), cfg.n_layers
+    if cfg.attn_pattern == "local_global":
+        g = cfg.local_per_global + 1
         return ({"n_layers": g}, g), ({"n_layers": 2 * g}, 2 * g), cfg.n_layers
     return ({"n_layers": 2}, 2), ({"n_layers": 4}, 4), cfg.n_layers
 

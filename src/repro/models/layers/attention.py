@@ -22,14 +22,17 @@ from repro.sharding.rules import constrain_batch_only
 NEG_INF = -1e30
 
 
-def init_attention(cfg, key, *, cross: bool = False):
+def init_attention(cfg, key, *, cross: bool = False, d_in: int = 0):
+    """q/k/v project from ``d_in`` (default d_model) channels; the output
+    projection maps back to d_model."""
     dt = dtype_of(cfg)
     d = cfg.d_model
+    d_in = d_in or d
     ks = jax.random.split(key, 4)
     return {
-        "wq": dense_init(ks[0], (d, cfg.q_dim), dt),
-        "wk": dense_init(ks[1], (d, cfg.kv_dim), dt),
-        "wv": dense_init(ks[2], (d, cfg.kv_dim), dt),
+        "wq": dense_init(ks[0], (d_in, cfg.q_dim), dt),
+        "wk": dense_init(ks[1], (d_in, cfg.kv_dim), dt),
+        "wv": dense_init(ks[2], (d_in, cfg.kv_dim), dt),
         "wo": dense_init(ks[3], (cfg.q_dim, d), dt),
     }
 
@@ -57,8 +60,10 @@ def _group(q: jnp.ndarray, n_kv: int) -> jnp.ndarray:
 
 # ----------------------------------------------------- chunked causal attention
 def chunked_attention(q, k, v, cfg, *, causal: bool = True,
-                      q_offset: int = 0) -> jnp.ndarray:
-    """Online-softmax attention over KV chunks.  q:(B,Sq,H,hd), k/v:(B,Skv,KV,hd)."""
+                      q_offset: int = 0,
+                      scale: Optional[float] = None) -> jnp.ndarray:
+    """Online-softmax attention over KV chunks.  q:(B,Sq,H,hd), k/v:(B,Skv,KV,hd).
+    Scores are scaled by ``scale``, default 1/sqrt(head_dim)."""
     B, Sq, H, hd = q.shape
     Skv = k.shape[1]
     KV = k.shape[2]
@@ -66,7 +71,7 @@ def chunked_attention(q, k, v, cfg, *, causal: bool = True,
     if Skv % ck:
         ck = math.gcd(Skv, ck) or Skv
     n_kv_chunks = Skv // ck
-    scale = 1.0 / math.sqrt(hd)
+    scale = 1.0 / math.sqrt(hd) if scale is None else scale
 
     # hoist the sequence all-gather of K/V: every query position attends over
     # the whole (seq-sharded) KV, so gather ONCE per layer here — otherwise
@@ -160,11 +165,14 @@ def banded_attention(q, k, v, cfg, *, window: int, q_offset: int = 0) -> jnp.nda
 
 
 # ------------------------------------------------------------------ full (enc)
-def full_attention(q, k, v, *, causal: bool) -> jnp.ndarray:
-    """Small-sequence dense attention (whisper encoder / cross-attn)."""
+def full_attention(q, k, v, *, causal: bool,
+                   scale: Optional[float] = None) -> jnp.ndarray:
+    """Small-sequence dense attention (whisper encoder / cross-attn).
+    Scores are scaled by ``scale``, default 1/sqrt(head_dim)."""
     B, Sq, H, hd = q.shape
     KV = k.shape[2]
-    qg = _group(q, KV).astype(jnp.float32) / math.sqrt(hd)
+    qg = _group(q, KV).astype(jnp.float32)
+    qg = qg / math.sqrt(hd) if scale is None else qg * scale
     s = jnp.einsum("bqkgh,bckh->bqkgc", qg, k.astype(jnp.float32))
     if causal:
         mask = jnp.tril(jnp.ones((Sq, k.shape[1]), bool))
@@ -175,13 +183,16 @@ def full_attention(q, k, v, *, causal: bool) -> jnp.ndarray:
 
 
 # --------------------------------------------------------------------- decode
-def decode_attention(q, k_cache, v_cache, kv_positions, pos, *, window: int = 0):
+def decode_attention(q, k_cache, v_cache, kv_positions, pos, *, window: int = 0,
+                     scale: Optional[float] = None):
     """One-token attention against a cache.
     q: (B,1,H,hd); caches: (B,C,KV,hd); kv_positions: (C,) absolute positions
-    (-1 = empty slot); pos: scalar current position."""
+    (-1 = empty slot); pos: scalar current position.  Scores are scaled by
+    ``scale``, default 1/sqrt(head_dim)."""
     B, _, H, hd = q.shape
     KV = k_cache.shape[2]
-    qg = _group(q, KV).astype(jnp.float32) / math.sqrt(hd)
+    qg = _group(q, KV).astype(jnp.float32)
+    qg = qg / math.sqrt(hd) if scale is None else qg * scale
     s = jnp.einsum("bqkgh,bckh->bqkgc", qg, k_cache.astype(jnp.float32))
     valid = (kv_positions >= 0) & (kv_positions <= pos)
     if window:

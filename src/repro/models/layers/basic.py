@@ -6,6 +6,7 @@ the activation dtype (bf16 on TPU).
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Dict
 
@@ -43,6 +44,10 @@ def dense_init(key, shape, dtype, scale: float | None = None):
 
 
 # ----------------------------------------------------------------------- norms
+#: every norm's epsilon, fixed here with no option
+NORM_EPS = 1e-6
+
+
 def init_norm(cfg, key):
     if cfg.norm == "nonparam_ln":  # olmo: no learned affine
         return {}
@@ -54,10 +59,10 @@ def apply_norm(params: Dict, x: jnp.ndarray, kind: str) -> jnp.ndarray:
     if kind in ("layernorm", "nonparam_ln"):
         mu = xf.mean(-1, keepdims=True)
         var = ((xf - mu) ** 2).mean(-1, keepdims=True)
-        y = (xf - mu) * jax.lax.rsqrt(var + 1e-6)
+        y = (xf - mu) * jax.lax.rsqrt(var + NORM_EPS)
     else:  # rmsnorm
         var = (xf**2).mean(-1, keepdims=True)
-        y = xf * jax.lax.rsqrt(var + 1e-6)
+        y = xf * jax.lax.rsqrt(var + NORM_EPS)
     if params:
         y = y * params["scale"].astype(jnp.float32)
     return y.astype(x.dtype)
@@ -90,6 +95,11 @@ def sinusoidal_positions(seq: int, d_model: int) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------------------- mlp
+#: ``cfg.act``: "gelu" is jax's tanh approximation, "gelu_exact" the erf form
+ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
+               "gelu_exact": functools.partial(jax.nn.gelu, approximate=False)}
+
+
 def init_mlp(cfg, key):
     dt = dtype_of(cfg)
     d, f = cfg.d_model, cfg.d_ff
@@ -103,7 +113,7 @@ def init_mlp(cfg, key):
 
 
 def apply_mlp(params: Dict, x: jnp.ndarray, cfg) -> jnp.ndarray:
-    act = jax.nn.silu if cfg.act == "silu" else jax.nn.gelu
+    act = ACTIVATIONS[cfg.act]
     if "wg" in params:
         return (act(x @ params["wg"]) * (x @ params["wi"])) @ params["wo"]
     return act(x @ params["wi"]) @ params["wo"]

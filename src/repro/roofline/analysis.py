@@ -173,7 +173,7 @@ def _attention_layer_counts(cfg):
     if cfg.family == "ssm":
         return 0, 0
     if cfg.family == "hybrid":
-        return cfg.n_layers // max(1, cfg.shared_attn_every), 0
+        return len(cfg.hybrid_ids), 0
     if cfg.attn_pattern == "swa":
         return 0, cfg.n_layers
     if cfg.attn_pattern == "local_global":

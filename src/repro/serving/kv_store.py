@@ -37,6 +37,18 @@ PAGE_SHARD_CONFIG = ServerConfig(device_size=1 << 30, table_capacity=1 << 14,
                                  segment_size=8 << 20)
 
 
+#: the leaves of a decode cache that only grow, a position at a time:
+#: attention keys and values, their int8 scales, and the positions their
+#: slots hold.  Every other leaf (an SSM or RWKV state, a conv window, the
+#: decode position) is state that each step rewrites whole.
+KV_LEAVES = frozenset({"k", "v", "k_scale", "v_scale", "kv_pos"})
+
+
+def leaf_kind(path) -> str:
+    """"kv" for an append-only attention-cache leaf, else "state"."""
+    return "kv" if getattr(path[-1], "key", None) in KV_LEAVES else "state"
+
+
 def _page_key(seq_id: int, name: str, idx: int) -> int:
     return splitmix64(hash((seq_id, name, idx)) & 0x7FFFFFFFFFFFFFFF) | 1
 
@@ -50,7 +62,9 @@ class ErdaKVPageStore:
         self.store = store or make_store("erda-cluster", n_shards=n_shards,
                                          replication=replication,
                                          cfg=PAGE_SHARD_CONFIG)
-        self.counters = {"snapshots": 0, "snapshot_bytes": 0, "restores": 0}
+        self.counters = {"snapshots": 0, "snapshot_bytes": 0,
+                         "snapshot_state_bytes": 0, "snapshot_kv_bytes": 0,
+                         "restores": 0}
 
     def put_page(self, seq_id: int, name: str, idx: int, array) -> None:
         self.store.write(_page_key(seq_id, name, idx), leaf_to_bytes(array))
@@ -75,25 +89,31 @@ class ErdaKVPageStore:
         """Persist a whole decode cache pytree as numbered pages — one batched
         multi_write (2 doorbells per shard), not one write per leaf.  A leaf
         larger than one record is split into record-sized pages, since a
-        record never spans a log segment."""
+        record never spans a log segment.  Counts the bytes of each kind of
+        leaf (``leaf_kind``) besides the total."""
         with obs.span("pages.snapshot", seq_id=seq_id) as sp:
             page = self.store.max_value_bytes
             items = []
+            by_kind = {"state": 0, "kv": 0}
             for path, leaf in jax.tree_util.tree_flatten_with_path(cache)[0]:
-                with obs.span("pages.to_host") as th:
+                kind = leaf_kind(path)
+                with obs.span("pages.to_host", kind=kind) as th:
                     host = np.asarray(leaf)
                     th.set(nbytes=host.nbytes)
-                with obs.span("pages.encode", nbytes=host.nbytes):
+                with obs.span("pages.encode", nbytes=host.nbytes, kind=kind):
                     blob = leaf_to_bytes(host)
                     name = jax.tree_util.keystr(path)
                     items += [(_page_key(seq_id, name, i),
                                blob[off : off + page])
                               for i, off in enumerate(range(0, len(blob),
                                                             page))]
+                by_kind[kind] += len(blob)
             self.store.multi_write(items)
             nbytes = sum(len(v) for _, v in items)
             self.counters["snapshots"] += 1
             self.counters["snapshot_bytes"] += nbytes
+            self.counters["snapshot_state_bytes"] += by_kind["state"]
+            self.counters["snapshot_kv_bytes"] += by_kind["kv"]
             sp.set(nbytes=nbytes)
             return len(items)
 
@@ -143,5 +163,6 @@ class ErdaKVPageStore:
         """Backing-store op counters — includes the location cache's
         ``spec_hits`` / ``spec_misses`` / ``spec_invalidations``, i.e. how
         often page re-fetches collapsed to one doorbell — plus this page
-        store's ``snapshots`` / ``snapshot_bytes`` / ``restores``."""
+        store's ``snapshots`` / ``snapshot_bytes`` (split into
+        ``snapshot_state_bytes`` and ``snapshot_kv_bytes``) / ``restores``."""
         return {**self.store.stats, **self.counters}

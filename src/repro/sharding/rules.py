@@ -131,6 +131,7 @@ _RULES = [
     (r"ssm/in_proj$",        2, (F, T)),
     (r"ssm/out_proj$",       2, (T, F)),
     (r"ssm/conv_w$",         2, (None, T)),
+    (r"ssm/conv_b$",         1, (T,)),
     (r"ssm/(A_log|D|dt_bias)$", 1, (None,)),
     (r"ssm/gate_norm$",      1, (T,)),
     (r"tm/w[rkvg]$",         2, (F, T)),

@@ -65,7 +65,7 @@ SCRIPT = textwrap.dedent("""
 
 
 @pytest.mark.parametrize("arch", ["olmo_1b", "mixtral_8x22b", "rwkv6_1p6b",
-                                  "gemma3_12b", "zamba2_1p2b"])
+                                  "gemma3_12b", "zamba2_1p2b", "zamba2_7b"])
 def test_small_mesh_dryrun(arch):
     r = subprocess.run([sys.executable, "-c", SCRIPT, arch],
                        capture_output=True, text=True, timeout=600,

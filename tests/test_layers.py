@@ -94,7 +94,7 @@ def test_ssm_chunked_matches_sequential(S_, chunk):
     dt = jnp.asarray(rng.uniform(0.01, 0.5, (Bsz, S_, nh)), jnp.float32)
     A_ = -jnp.asarray(rng.uniform(0.5, 2.0, (nh,)), jnp.float32)
     D = jnp.asarray(rng.standard_normal((nh,)), jnp.float32)
-    y, h = S.ssm_chunked(cfg, x, B_in, C_in, dt, A_)
+    y, h = S.ssm_chunked(cfg, x, B_in[:, :, None], C_in[:, :, None], dt, A_)  # one group
     y = y + x * D[None, None, :, None]
     want = ssm_sequential_ref(x, B_in, C_in, dt, A_, D)
     np.testing.assert_allclose(np.asarray(y), want, rtol=1e-4, atol=1e-4)

@@ -59,7 +59,8 @@ def test_prefill_then_decode(arch):
         token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
 
 
-@pytest.mark.parametrize("arch", ["olmo_1b", "rwkv6_1p6b", "zamba2_1p2b", "mixtral_8x22b"])
+@pytest.mark.parametrize("arch", ["olmo_1b", "rwkv6_1p6b", "zamba2_1p2b",
+                                  "zamba2_7b", "mixtral_8x22b"])
 def test_decode_matches_prefill(arch):
     """Teacher-forcing consistency: decoding token t with the prefill(0..t-1)
     cache must equal prefilling 0..t — same logits.  fp32 so that genuine
@@ -141,6 +142,11 @@ def test_full_configs_match_assignment():
     assert (c.n_layers, c.d_model, c.d_ff, c.vocab_size) == (24, 2048, 7168, 65_536)
     c = get_config("zamba2_1p2b")
     assert (c.n_layers, c.d_model, c.ssm_state) == (38, 2048, 64)
+    c = get_config("zamba2_7b")
+    assert (c.n_layers, c.d_model, c.n_heads, c.head_dim, c.d_ff, c.vocab_size,
+            c.ssm_state, c.ssm_heads, c.ssm_groups, c.n_mem_blocks,
+            c.adapter_rank, len(c.hybrid_layer_ids)) == \
+        (81, 3584, 32, 224, 14_336, 32_000, 64, 112, 2, 2, 128, 13)
     c = get_config("whisper_small")
     assert (c.n_layers, c.encoder_layers, c.d_model, c.vocab_size) == (12, 12, 768, 51_865)
     c = get_config("olmo_1b")

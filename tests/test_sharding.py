@@ -100,7 +100,7 @@ def test_cache_spec_seq_parallel_for_batch1():
 
 def test_every_arch_param_tree_has_specs():
     i = info(pod=2)
-    for arch in ("olmo_1b", "mixtral_8x22b", "zamba2_1p2b", "rwkv6_1p6b",
+    for arch in ("olmo_1b", "mixtral_8x22b", "zamba2_1p2b", "zamba2_7b", "rwkv6_1p6b",
                  "whisper_small", "gemma3_27b"):
         cfg = get_config(arch)
         model = get_model(cfg)
