@@ -34,7 +34,6 @@ def encoded_size(shape, dtype) -> int:
 
 def leaf_from_bytes(buf: bytes) -> np.ndarray:
     (mlen,) = struct.unpack_from("<I", buf, 0)
-    meta = json.loads(buf[4 : 4 + mlen].decode())
-    data = buf[4 + mlen :]
-    return np.frombuffer(data, dtype=_resolve_dtype(meta["dtype"])).reshape(
-        meta["shape"]).copy()
+    meta = json.loads(bytes(buf[4 : 4 + mlen]).decode())
+    return np.frombuffer(buf, dtype=_resolve_dtype(meta["dtype"]),
+                         offset=4 + mlen).reshape(meta["shape"]).copy()
