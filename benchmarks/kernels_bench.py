@@ -30,14 +30,4 @@ def bench_kernels() -> List[Dict]:
     rows.append({"figure": "kernel", "name": "crc32_batch 512x1KiB",
                  "pallas_us": round(t_k * 1e6, 1), "ref_us": round(t_r * 1e6, 1),
                  "bytes": int(data.size * 4)})
-
-    q = jnp.asarray(rng.standard_normal((4, 256, 64)), jnp.float32)
-    fa = lambda q_: __import__("repro.kernels.flash_attention", fromlist=["x"]) \
-        .flash_attention_pallas(q_, q_, q_, interpret=True)
-    t_k = _time(fa, q)
-    t_r = _time(jax.jit(lambda q_: ref.attention_ref(q_, q_, q_)), q)
-    flops = 4 * 4 * 256 * 256 * 64
-    rows.append({"figure": "kernel", "name": "flash_attention 4x256x64",
-                 "pallas_us": round(t_k * 1e6, 1), "ref_us": round(t_r * 1e6, 1),
-                 "flops": flops})
     return rows

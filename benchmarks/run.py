@@ -1,6 +1,6 @@
 """Benchmark driver — one function per paper table/figure.
 
-    PYTHONPATH=src python -m benchmarks.run [--skip-roofline] [--only a,b,...]
+    PYTHONPATH=src python -m benchmarks.run [--only a,b,...]
 
 ``--only`` runs just the named figures (e.g. ``--only replication,batching``
 — what the CI benchmark-smoke step uses).  Prints ``name,us_per_call,derived``
@@ -20,12 +20,11 @@ sys.path.insert(0, "src")
 FIGURES = ("latency", "throughput", "cpu_cost", "cleaning", "cluster",
            "batching", "replication", "quorum", "serving_load", "serving_slo",
            "read_speculation", "resharding", "ycsb_driver", "nvm_writes",
-           "kernels", "checkpoint", "roofline")
+           "kernels", "checkpoint")
 
 
 def main() -> None:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--skip-roofline", action="store_true")
     ap.add_argument("--only", default="",
                     help="comma-separated figure names to run (default: all)")
     args, _ = ap.parse_known_args()
@@ -284,15 +283,6 @@ def main() -> None:
             print(f"checkpoint/{r['name'].replace(' ', '_')},,"
                   f"erda_wamp={r['write_amplification_erda']} "
                   f"redo_wamp={r['write_amplification_redo']} ratio={r['ratio']}")
-
-    if not args.skip_roofline and want("roofline"):
-        from benchmarks.roofline_report import summarize
-        rows = summarize()  # empty until the dry-run sweep has written reports
-        all_rows += rows
-        for r in rows[:80]:
-            extra = (f"frac={r['roofline_frac']}" if "roofline_frac" in r
-                     else r.get("note", ""))
-            print(f"roofline/{r['cell']},,dominant={r['dominant']} {extra}")
 
     out = pathlib.Path("artifacts")
     out.mkdir(exist_ok=True)

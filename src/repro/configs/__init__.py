@@ -1,5 +1,3 @@
-from repro.configs.base import (ARCH_IDS, SHAPES, SUBQUADRATIC, ModelConfig,
-                                ShapeConfig, all_cells, cell_applicable, get_config)
+from repro.configs.base import ModelConfig, ShapeConfig, get_config
 
-__all__ = ["ARCH_IDS", "SHAPES", "SUBQUADRATIC", "ModelConfig", "ShapeConfig",
-           "all_cells", "cell_applicable", "get_config"]
+__all__ = ["ModelConfig", "ShapeConfig", "get_config"]

@@ -45,16 +45,7 @@ class SyntheticTokens:
         return {"tokens": toks}
 
 
-def make_batch(cfg, shape, step: int = 0, extras: bool = True):
-    """Concrete numpy batch matching input_specs(shape) for train/prefill."""
-    ds = SyntheticTokens(cfg.vocab_size, shape.seq_len, shape.global_batch)
-    batch = ds.batch(step)
-    if extras:
-        rng = np.random.default_rng(step + 99)
-        if cfg.family == "encdec":
-            batch["frames"] = rng.standard_normal(
-                (shape.global_batch, cfg.encoder_seq, cfg.d_model)).astype(np.float32) * 0.02
-        if cfg.family == "vlm":
-            batch["patches"] = rng.standard_normal(
-                (shape.global_batch, cfg.n_patches, cfg.d_model)).astype(np.float32) * 0.02
-    return batch
+def make_batch(cfg, shape, step: int = 0):
+    """Concrete numpy token batch for a train/prefill ``ShapeConfig``."""
+    return SyntheticTokens(cfg.vocab_size, shape.seq_len,
+                           shape.global_batch).batch(step)

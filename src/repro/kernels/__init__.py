@@ -1,8 +1,7 @@
-# Two TPU Pallas kernels for this system's compute hot-spots:
+# The TPU Pallas kernel for this system's compute hot-spot:
 #   crc32.py           — batch object/shard CRC verification (the paper's §4.2
 #                        verify step, restructured lane-parallel for the VPU)
-#   flash_attention.py — blocked causal attention (serving/training substrate)
-# ops.py holds the jit'd public wrappers; ref.py the pure-jnp oracles.
+# ops.py holds the jit'd public wrappers; ref.py the pure-jnp oracle.
 from repro.kernels import ops, ref
 
 __all__ = ["ops", "ref"]

@@ -1,7 +1,5 @@
-"""Pure-jnp oracles for the Pallas kernels."""
+"""Pure-jnp oracle for the Pallas CRC32 kernel."""
 from __future__ import annotations
-
-import math
 
 import jax
 import jax.numpy as jnp
@@ -28,18 +26,3 @@ def crc32_ref(data: jax.Array) -> jax.Array:
     init = jnp.full((n,), 0xFFFFFFFF, jnp.uint32)
     crc, _ = jax.lax.scan(word_step, init, jnp.moveaxis(data, 1, 0))
     return crc ^ jnp.uint32(0xFFFFFFFF)
-
-
-def attention_ref(q: jax.Array, k: jax.Array, v: jax.Array, *,
-                  causal: bool = True) -> jax.Array:
-    """Dense softmax attention oracle.  q,k,v: (BH, S, hd)."""
-    s = q.shape[1]
-    scale = 1.0 / math.sqrt(q.shape[-1])
-    logits = jnp.einsum("bqh,bkh->bqk", q.astype(jnp.float32) * scale,
-                        k.astype(jnp.float32))
-    if causal:
-        mask = jnp.tril(jnp.ones((s, s), bool))
-        logits = jnp.where(mask[None], logits, -1e30)
-    p = jax.nn.softmax(logits, axis=-1)
-    o = jnp.einsum("bqk,bkh->bqh", p, v.astype(jnp.float32))
-    return o.astype(q.dtype)

@@ -16,13 +16,13 @@ from repro.checkpoint import ErdaCheckpointManager
 from repro.sharding import MeshInfo, param_specs
 
 
-def reshard_restore(mgr: ErdaCheckpointManager, template, mesh, n_experts=0):
+def reshard_restore(mgr: ErdaCheckpointManager, template, mesh):
     """Restore the newest consistent checkpoint onto `mesh` (any size)."""
     step, state = mgr.restore(template)
     if step is None:
         return None, None
     info = MeshInfo(mesh)
-    pspec = param_specs(state["params"], info, n_experts)
+    pspec = param_specs(state["params"], info)
 
     def put(leaf, spec):
         return jax.device_put(jnp.asarray(leaf),

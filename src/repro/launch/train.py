@@ -15,7 +15,6 @@ import time
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 
 from repro.checkpoint import ErdaCheckpointManager
 from repro.checkpoint.erda_ckpt import WriterCrash
@@ -37,11 +36,7 @@ def scale_config(cfg, scale: str):
     if scale == "100m":  # ~100M params, runnable on CPU for a few hundred steps
         return dataclasses.replace(
             cfg, n_layers=6, d_model=512, n_heads=8, n_kv_heads=8, head_dim=64,
-            d_ff=2048, vocab_size=8192, window=min(cfg.window, 256) if cfg.window else 0,
-            n_experts=min(cfg.n_experts, 8), n_experts_active=min(cfg.n_experts_active, 2),
-            encoder_seq=min(cfg.encoder_seq, 64) if cfg.encoder_seq else 0,
-            n_patches=min(cfg.n_patches, 16) if cfg.n_patches else 0,
-            attn_chunk=256, remat="none",
+            d_ff=2048, vocab_size=8192, attn_chunk=256, remat="none",
             tie_embeddings=False)  # untied head learns faster from small init
     raise ValueError(scale)
 

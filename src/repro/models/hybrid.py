@@ -33,7 +33,6 @@ from repro.models.layers import attention as A
 from repro.models.layers import basic as B
 from repro.models.layers import ssm as S
 from repro.models.transformer import CACHE_PAD, _full_cache_from_kv
-from repro.sharding.rules import constrain_batch
 
 
 def _calls(cfg):
@@ -86,7 +85,6 @@ def init_lm(cfg, key):
 # ------------------------------------------------------------------- blocks
 def _mamba(cfg, lp, h, tau=None, state=None, decode=False):
     """One Mamba2 layer: h + Mamba2(RMSNorm(h [+ tau]))."""
-    h = constrain_batch(h)
     with jax.named_scope("mamba2"):
         u = B.apply_norm(lp["ln"], h if tau is None else h + tau, cfg.norm)
         if decode:
@@ -115,10 +113,9 @@ def _shared_fwd(cfg, params, j, h, x0, positions):
         u = B.apply_norm(sp["ln1"], jnp.concatenate([h, x0], -1), cfg.norm)
         q, k, v = A.qkv(sp["attn"], u, cfg, positions)
         if h.shape[1] <= 512:
-            o = A.full_attention(q, k, v, causal=True, scale=_attn_scale(cfg))
+            o = A.full_attention(q, k, v, scale=_attn_scale(cfg))
         else:
-            o = A.chunked_attention(q, k, v, cfg, causal=True,
-                                    scale=_attn_scale(cfg))
+            o = A.chunked_attention(q, k, v, cfg, scale=_attn_scale(cfg))
         a = o.reshape(h.shape[0], h.shape[1], cfg.q_dim) @ sp["attn"]["wo"]
         return _mlp_out(cfg, sp, cp, a), (k, v)
 
@@ -166,7 +163,7 @@ def train_loss(cfg, params, batch):
     x, _, _ = _forward(cfg, params, x, jnp.arange(x.shape[1]))
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
     return B.lm_loss_chunked(params["embed"], x, batch["tokens"],
-                             chunk=cfg.loss_chunk, unroll=cfg.unroll)
+                             chunk=cfg.loss_chunk)
 
 
 def prefill(cfg, params, batch):

@@ -13,25 +13,6 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from repro.sharding.rules import constrain_batch
-
-
-def scan_layers(body, carry, xs, *, unroll: bool = False):
-    """lax.scan over stacked layer params — or an unrolled Python loop in
-    measurement mode (so cost_analysis sees every layer)."""
-    if not unroll:
-        return jax.lax.scan(body, carry, xs)
-    n = jax.tree.leaves(xs)[0].shape[0]
-    ys = []
-    for i in range(n):
-        sl = jax.tree.map(lambda a: a[i], xs)
-        carry, y = body(carry, sl)
-        ys.append(y)
-    if all(len(jax.tree.leaves(y)) == 0 for y in ys):
-        return carry, ys[0]
-    stacked = jax.tree.map(lambda *zz: jnp.stack(zz), *ys)
-    return carry, stacked
-
 
 def dtype_of(cfg):
     return jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
@@ -87,13 +68,6 @@ def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndar
     return out.astype(x.dtype)
 
 
-def sinusoidal_positions(seq: int, d_model: int) -> jnp.ndarray:
-    pos = jnp.arange(seq, dtype=jnp.float32)[:, None]
-    dim = jnp.arange(0, d_model, 2, dtype=jnp.float32)[None, :]
-    angle = pos / jnp.power(10_000.0, dim / d_model)
-    return jnp.concatenate([jnp.sin(angle), jnp.cos(angle)], axis=-1)
-
-
 # ------------------------------------------------------------------------- mlp
 #: ``cfg.act``: "gelu" is jax's tanh approximation, "gelu_exact" the erf form
 ACTIVATIONS = {"silu": jax.nn.silu, "gelu": jax.nn.gelu,
@@ -140,16 +114,8 @@ def unembed(params: Dict, x: jnp.ndarray) -> jnp.ndarray:
 
 
 # ------------------------------------------------------------------------ loss
-def cross_entropy_loss(logits: jnp.ndarray, targets: jnp.ndarray) -> jnp.ndarray:
-    """Mean next-token CE in fp32; targets = tokens shifted by caller."""
-    logits = logits.astype(jnp.float32)
-    logz = jax.nn.logsumexp(logits, axis=-1)
-    gold = jnp.take_along_axis(logits, targets[..., None], axis=-1)[..., 0]
-    return (logz - gold).mean()
-
-
 def lm_loss_chunked(embed_params: Dict, x: jnp.ndarray, tokens: jnp.ndarray,
-                    chunk: int = 512, unroll: bool = False) -> jnp.ndarray:
+                    chunk: int = 512) -> jnp.ndarray:
     """Fused unembed + next-token CE, scanned over sequence chunks so the
     (B,S,V) fp32 logits tensor never materializes — at 262k vocab that buffer
     alone would be 4 GB/chip.  The chunk body is rematerialized in the
@@ -168,7 +134,6 @@ def lm_loss_chunked(embed_params: Dict, x: jnp.ndarray, tokens: jnp.ndarray,
     @jax.checkpoint
     def body(acc, xs):
         xc, tc, wc = xs
-        xc = constrain_batch(xc)
         logits = unembed(embed_params, xc).astype(jnp.float32)
         logz = jax.nn.logsumexp(logits, axis=-1)
         gold = jnp.take_along_axis(logits, tc[..., None], axis=-1)[..., 0]
@@ -177,10 +142,5 @@ def lm_loss_chunked(embed_params: Dict, x: jnp.ndarray, tokens: jnp.ndarray,
     xs = (jnp.moveaxis(x.reshape(B, n, c, -1), 1, 0),
           jnp.moveaxis(targets.reshape(B, n, c), 1, 0),
           jnp.moveaxis(weights.reshape(B, n, c), 1, 0))
-    if unroll:
-        total = jnp.float32(0.0)
-        for i in range(n):
-            total, _ = body(total, jax.tree.map(lambda a: a[i], xs))
-    else:
-        total, _ = jax.lax.scan(body, jnp.float32(0.0), xs)
+    total, _ = jax.lax.scan(body, jnp.float32(0.0), xs)
     return total / weights.sum()

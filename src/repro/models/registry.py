@@ -1,63 +1,37 @@
 """Model registry: uniform functional interface per architecture family.
 
 get_model(cfg) → Model with:
-  init(key, max_seq)            — parameters (stacked for scan)
+  init(key, max_seq)            — parameters (no served family's depend on
+                                  max_seq; callers may still pass it)
   train_loss(params, batch)     — scalar loss
   prefill(params, batch)        — (last_logits, cache)
   decode_step(params, cache, token)
-  init_cache(batch, seq_len)    — empty cache for serve_step lowering
-  input_specs(shape, kind)      — ShapeDtypeStruct stand-ins for every input
+  init_cache(batch, seq_len)    — empty cache with room for seq_len tokens
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 from types import SimpleNamespace
 
 import jax
-import jax.numpy as jnp
 
-from repro.configs.base import ModelConfig, ShapeConfig
-from repro.models import encdec, hybrid, rwkv_model, transformer
+from repro.configs.base import ModelConfig
+from repro.models import hybrid, rwkv_model, transformer
 
-
-def _family_module(cfg: ModelConfig):
-    if cfg.family in ("dense", "moe", "vlm"):
-        return transformer
-    if cfg.family == "encdec":
-        return encdec
-    if cfg.family == "hybrid":
-        return hybrid
-    if cfg.family == "ssm":
-        return rwkv_model
-    raise ValueError(f"unknown family {cfg.family}")
+#: ``ModelConfig.family`` → the module that implements it
+FAMILIES = {"dense": transformer, "ssm": rwkv_model, "hybrid": hybrid}
 
 
 def get_model(cfg: ModelConfig) -> SimpleNamespace:
-    mod = _family_module(cfg)
+    if cfg.family not in FAMILIES:
+        raise ValueError(f"unknown family {cfg.family}")
+    mod = FAMILIES[cfg.family]
 
     def init(key, max_seq: int = 4096):
-        if cfg.family == "encdec":
-            return mod.init_lm(cfg, key, max_seq)
         return mod.init_lm(cfg, key)
 
     def init_abstract(max_seq: int = 4096):
         return jax.eval_shape(lambda: init(jax.random.PRNGKey(0), max_seq))
-
-    def input_specs(shape: ShapeConfig):
-        B, S = shape.global_batch, shape.seq_len
-        tok = jax.ShapeDtypeStruct((B, S), jnp.int32)
-        dt = jnp.bfloat16 if cfg.dtype == "bfloat16" else jnp.float32
-        if shape.kind in ("train", "prefill"):
-            batch = {"tokens": tok}
-            if cfg.family == "encdec":
-                batch["frames"] = jax.ShapeDtypeStruct((B, cfg.encoder_seq, cfg.d_model), dt)
-            if cfg.family == "vlm":
-                batch["patches"] = jax.ShapeDtypeStruct((B, cfg.n_patches, cfg.d_model), dt)
-            return batch
-        # decode: one token + a cache holding seq_len of history
-        cache = jax.eval_shape(lambda: mod.init_cache(cfg, B, S))
-        return {"token": jax.ShapeDtypeStruct((B, 1), jnp.int32), "cache": cache}
 
     return SimpleNamespace(
         cfg=cfg,
@@ -67,5 +41,4 @@ def get_model(cfg: ModelConfig) -> SimpleNamespace:
         prefill=functools.partial(mod.prefill, cfg),
         decode_step=functools.partial(mod.decode_step, cfg),
         init_cache=functools.partial(mod.init_cache, cfg),
-        input_specs=input_specs,
     )

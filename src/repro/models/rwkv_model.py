@@ -1,6 +1,6 @@
 """RWKV6 language model: attention-free; each block = time-mix + channel-mix
 with token-shift.  Decode carries (shift, wkv-state) per layer — O(1) memory in
-context length, which is why the long_500k cell runs on this arch."""
+context length."""
 from __future__ import annotations
 
 import jax
@@ -8,7 +8,6 @@ import jax.numpy as jnp
 
 from repro.models.layers import basic as B
 from repro.models.layers import rwkv as R
-from repro.sharding.rules import constrain_batch
 
 
 def init_lm(cfg, key):
@@ -29,7 +28,6 @@ def init_lm(cfg, key):
 
 
 def _block(cfg, lp, x, state=None):
-    x = constrain_batch(x)
     tm_state = None if state is None else state["tm"]
     cm_state = None if state is None else state["cm"]
     h = B.apply_norm(lp["ln1"], x, cfg.norm)
@@ -49,7 +47,7 @@ def _forward(cfg, params, x, collect: bool):
         return h, (st if collect else None)
 
     body_fn = jax.checkpoint(body) if remat else body
-    return B.scan_layers(body_fn, x, params["layers"], unroll=cfg.unroll)
+    return jax.lax.scan(body_fn, x, params["layers"])
 
 
 def train_loss(cfg, params, batch):
@@ -58,7 +56,7 @@ def train_loss(cfg, params, batch):
     x, _ = _forward(cfg, params, x, collect=False)
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
     return B.lm_loss_chunked(params["embed"], x, batch["tokens"],
-                             chunk=cfg.loss_chunk, unroll=cfg.unroll)
+                             chunk=cfg.loss_chunk)
 
 
 def prefill(cfg, params, batch):
@@ -85,8 +83,7 @@ def decode_step(cfg, params, cache, token):
         h, new_st = _block(cfg, lp, h, state=st)
         return h, new_st
 
-    x, new_states = B.scan_layers(body, x, (params["layers"], cache["layers"]),
-                                  unroll=cfg.unroll)
+    x, new_states = jax.lax.scan(body, x, (params["layers"], cache["layers"]))
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
     logits = B.unembed(params["embed"], x)
     return logits, {"pos": cache["pos"] + 1, "layers": new_states}

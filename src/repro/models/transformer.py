@@ -1,76 +1,50 @@
-"""Decoder-only transformer LM covering the dense / swa / local_global / moe
-families (+ the pixtral VLM, which prepends stub patch embeddings).
+"""Decoder-only dense transformer LM.
 
-Layers are scanned with stacked parameters: HLO size is O(1) in depth, which
-keeps the 512-device dry-run compiles tractable.  gemma3's 5:1 local:global
-pattern scans over GROUPS (inner scan over 5 stacked local layers + one
-unrolled global layer per group).
+Layers are scanned with stacked parameters: the program's size is O(1) in
+depth.
 
 Step functions:
-  train_loss(params, batch)                 — next-token CE (+ MoE aux loss)
+  train_loss(params, batch)                 — next-token CE
   prefill(params, batch)                    — returns (last_logits, cache)
   decode_step(params, cache, token)         — one token against the cache
-KV caches: full/global layers hold (L,B,C,KV,hd) with absolute positions;
-sliding-window layers hold W-slot ring buffers — at 500k context only 1-in-6
-gemma3 layers pays O(S) memory.
+The KV cache holds (L,B,C,KV,hd) keys and values with their absolute
+positions.
 """
 from __future__ import annotations
-
-import dataclasses
-import functools
-from typing import Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.layers import attention as A
 from repro.models.layers import basic as B
-from repro.models.layers import moe as M
-from repro.sharding.rules import constrain_batch
 
 CACHE_PAD = 128  # decode caches get S + CACHE_PAD capacity
 
 
 # ---------------------------------------------------------------------- blocks
-def init_block(cfg, key, kind: str):
+def init_block(cfg, key):
     ks = jax.random.split(key, 4)
-    p = {"ln1": B.init_norm(cfg, ks[0]), "attn": A.init_attention(cfg, ks[1]),
-         "ln2": B.init_norm(cfg, ks[2])}
-    if cfg.n_experts and kind != "local":  # (all layers MoE in our moe archs)
-        p["moe"] = M.init_moe(cfg, ks[3])
-    else:
-        p["mlp"] = B.init_mlp(cfg, ks[3])
-    return p
+    return {"ln1": B.init_norm(cfg, ks[0]), "attn": A.init_attention(cfg, ks[1]),
+            "ln2": B.init_norm(cfg, ks[2]), "mlp": B.init_mlp(cfg, ks[3])}
 
 
 def _mix(cfg, p, x, attn_out):
-    """Residual attn-out projection + MLP/MoE.  Returns (x, aux_loss)."""
+    """Residual attn-out projection + MLP."""
     x = x + attn_out @ p["attn"]["wo"]
     h = B.apply_norm(p["ln2"], x, cfg.norm)
-    aux = jnp.float32(0.0)
-    if "moe" in p:
-        aux = M.aux_load_balance_loss(p["moe"], h, cfg)
-        x = x + M.apply_moe(p["moe"], h, cfg)
-    else:
-        x = x + B.apply_mlp(p["mlp"], h, cfg)
-    return x, aux
+    return x + B.apply_mlp(p["mlp"], h, cfg)
 
 
-def block_fwd(cfg, p, x, positions, kind: str):
-    """kind: 'full' | 'window'."""
-    x = constrain_batch(x)
+def block_fwd(cfg, p, x, positions):
     B_, S, _ = x.shape
     h = B.apply_norm(p["ln1"], x, cfg.norm)
     q, k, v = A.qkv(p["attn"], h, cfg, positions)
-    if kind == "window" and cfg.window and S > cfg.window:
-        o = A.banded_attention(q, k, v, cfg, window=cfg.window)
-    elif S <= 512:
-        o = A.full_attention(q, k, v, causal=True)
+    if S <= 512:
+        o = A.full_attention(q, k, v)
     else:
-        o = A.chunked_attention(q, k, v, cfg, causal=True)
+        o = A.chunked_attention(q, k, v, cfg)
     o = o.reshape(B_, S, cfg.q_dim)
-    x, aux = _mix(cfg, p, x, o)
-    return x, (k, v), aux
+    return _mix(cfg, p, x, o), (k, v)
 
 
 def _quantize_kv(t):
@@ -81,144 +55,58 @@ def _quantize_kv(t):
     return q, scale.astype(jnp.bfloat16)
 
 
-def block_decode(cfg, p, x, lcache, pos, kind: str):
+def block_decode(cfg, p, x, lcache, pos):
     """x: (B,1,d); lcache: dict(k,v,kv_pos[,k_scale,v_scale]) for this layer."""
-    x = constrain_batch(x)
     B_ = x.shape[0]
     h = B.apply_norm(p["ln1"], x, cfg.norm)
     q, k, v = A.qkv(p["attn"], h, cfg, jnp.full((1,), pos))
-    ring = lcache["k"].shape[1] if kind == "window" else 0
     if cfg.cache_quant and "k_scale" in lcache:
         kq, ks_new = _quantize_kv(k)
         vq, vs_new = _quantize_kv(v)
         kc, vc, kp = A.cache_update(lcache["k"], lcache["v"], lcache["kv_pos"],
-                                    kq, vq, pos, ring=ring)
+                                    kq, vq, pos)
         ks, vs, _ = A.cache_update(lcache["k_scale"], lcache["v_scale"],
-                                   lcache["kv_pos"], ks_new, vs_new, pos, ring=ring)
+                                   lcache["kv_pos"], ks_new, vs_new, pos)
         # dequant fuses into the attention einsums on TPU: HBM reads stay int8
         kd = (kc.astype(jnp.bfloat16) * ks).astype(q.dtype)
         vd = (vc.astype(jnp.bfloat16) * vs).astype(q.dtype)
-        o = A.decode_attention(q, kd, vd, kp, pos,
-                               window=cfg.window if kind == "window" else 0)
+        o = A.decode_attention(q, kd, vd, kp, pos)
         new_cache = {"k": kc, "v": vc, "kv_pos": kp, "k_scale": ks, "v_scale": vs}
     else:
         kc, vc, kp = A.cache_update(lcache["k"], lcache["v"], lcache["kv_pos"],
-                                    k, v, pos, ring=ring)
-        o = A.decode_attention(q, kc, vc, kp, pos,
-                               window=cfg.window if kind == "window" else 0)
+                                    k, v, pos)
+        o = A.decode_attention(q, kc, vc, kp, pos)
         new_cache = {"k": kc, "v": vc, "kv_pos": kp}
     o = o.reshape(B_, 1, cfg.q_dim)
-    x, _aux = _mix(cfg, p, x, o)
-    return x, new_cache
+    return _mix(cfg, p, x, o), new_cache
 
 
-# ----------------------------------------------------------------- layer plans
-def layer_plan(cfg) -> Tuple[str, ...]:
-    """Per-layer attention kind."""
-    if cfg.attn_pattern == "swa":
-        return ("window",) * cfg.n_layers
-    if cfg.attn_pattern == "local_global":
-        g = cfg.local_per_global + 1
-        pat = ("window",) * cfg.local_per_global + ("full",)
-        reps = cfg.n_layers // g
-        rem = cfg.n_layers - reps * g
-        return pat * reps + ("window",) * rem
-    return ("full",) * cfg.n_layers
-
-
-def _stack_init(cfg, key, n, kind):
-    keys = jax.random.split(key, n)
-    return jax.vmap(lambda k: init_block(cfg, k, kind))(keys)
-
-
+# -------------------------------------------------------------------- params
 def init_lm(cfg, key):
     ks = jax.random.split(key, 4)
-    p = {"embed": B.init_embedding(cfg, ks[0]),
-         "final_norm": B.init_norm(cfg, ks[1])}
-    if cfg.attn_pattern == "local_global":
-        g = cfg.local_per_global + 1
-        G = cfg.n_layers // g
-        rem = cfg.n_layers - G * g  # e.g. gemma3-27b: 62 = 10×6 + 2
-        kl, kg = jax.random.split(ks[2])
-        loc_keys = jax.random.split(kl, G * cfg.local_per_global)
-        p["local_layers"] = jax.vmap(lambda k: init_block(cfg, k, "local"))(
-            loc_keys)
-        p["local_layers"] = jax.tree.map(
-            lambda a: a.reshape((G, cfg.local_per_global) + a.shape[1:]),
-            p["local_layers"])
-        p["global_layers"] = _stack_init(cfg, kg, G, "full")
-        if rem:
-            p["tail_local"] = _stack_init(cfg, jax.random.fold_in(key, 3),
-                                          rem, "local")
-    else:
-        p["layers"] = _stack_init(cfg, ks[2], cfg.n_layers, cfg.attn_pattern)
-    return p
+    layer_keys = jax.random.split(ks[2], cfg.n_layers)
+    return {"embed": B.init_embedding(cfg, ks[0]),
+            "final_norm": B.init_norm(cfg, ks[1]),
+            "layers": jax.vmap(lambda k: init_block(cfg, k))(layer_keys)}
 
 
 # --------------------------------------------------------------------- forward
-def _embed_inputs(cfg, params, batch):
-    x = B.embed(params["embed"], batch["tokens"])
-    if cfg.family == "vlm":
-        x = jnp.concatenate([batch["patches"].astype(x.dtype), x], axis=1)
-    x = constrain_batch(x)
-    S = x.shape[1]
-    positions = jnp.arange(S)
-    return x, positions
-
-
 def _backbone(cfg, params, x, positions, *, collect_kv: bool):
     """Returns (x, kv_stacks) — kv_stacks is None unless collect_kv."""
-    remat = cfg.remat == "full"
-    scan = functools.partial(B.scan_layers, unroll=cfg.unroll)
-
-    if cfg.attn_pattern == "local_global":
-        def local_body(h, lp):
-            out, kv, aux = block_fwd(cfg, lp, h, positions, "window")
-            return out, ((kv if collect_kv else None), aux)
-
-        def group_body(h, xs):
-            lp, gp = xs
-            h, (lkv, laux) = scan(
-                jax.checkpoint(local_body) if remat else local_body, h, lp)
-            h, gkv, gaux = block_fwd(cfg, gp, h, positions, "full")
-            return h, (((lkv, gkv) if collect_kv else None), laux.sum() + gaux)
-
-        # remat the WHOLE group: otherwise the outer scan stacks the global
-        # layer's attention residuals across all G groups (tens of GiB)
-        group_fn = jax.checkpoint(group_body) if remat else group_body
-        x, (kvs, aux) = scan(
-            group_fn, x, (params["local_layers"], params["global_layers"]))
-        aux = aux.sum()
-        tail_kvs = None
-        if "tail_local" in params:
-            x, (tail_kvs, taux) = scan(
-                jax.checkpoint(local_body) if remat else local_body,
-                x, params["tail_local"])
-            aux = aux + taux.sum()
-        return x, ((kvs, tail_kvs) if collect_kv else None), aux
-
-    kind = "window" if cfg.attn_pattern == "swa" else "full"
-
     def body(h, lp):
-        out, kv, aux = block_fwd(cfg, lp, h, positions, kind)
-        return out, ((kv if collect_kv else None), aux)
+        out, kv = block_fwd(cfg, lp, h, positions)
+        return out, (kv if collect_kv else None)
 
-    body_fn = jax.checkpoint(body) if remat else body
-    x, (kvs, aux) = scan(body_fn, x, params["layers"])
-    return x, kvs, aux.sum()
+    body_fn = jax.checkpoint(body) if cfg.remat == "full" else body
+    return jax.lax.scan(body_fn, x, params["layers"])
 
 
 def train_loss(cfg, params, batch):
-    x, positions = _embed_inputs(cfg, params, batch)
-    x, _, aux = _backbone(cfg, params, x, positions, collect_kv=False)
+    x = B.embed(params["embed"], batch["tokens"])
+    x, _ = _backbone(cfg, params, x, jnp.arange(x.shape[1]), collect_kv=False)
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
-    if cfg.family == "vlm":
-        x = x[:, cfg.n_patches :]  # loss only on text positions
-    loss = B.lm_loss_chunked(params["embed"], x, batch["tokens"],
-                             chunk=cfg.loss_chunk, unroll=cfg.unroll)
-    if cfg.n_experts:
-        loss = loss + 0.01 * aux / cfg.n_layers
-    return loss
+    return B.lm_loss_chunked(params["embed"], x, batch["tokens"],
+                             chunk=cfg.loss_chunk)
 
 
 # ---------------------------------------------------------------------- caches
@@ -231,133 +119,42 @@ def _full_cache_from_kv(k, v, S, pad=CACHE_PAD):
     return {"k": kc, "v": vc, "kv_pos": kv_pos}
 
 
-def _ring_cache_from_kv(k, v, S, W):
-    """Keep the last W tokens, laid out so slot = pos % W."""
-    B_, _, KV, hd = k.shape
-    if S >= W:
-        pos = jnp.arange(S - W, S, dtype=jnp.int32)
-        kw, vw = k[:, S - W :], v[:, S - W :]
-        # rotate so that slot index == position % W (the ring invariant)
-        shift = jnp.mod(pos[0], W)
-        idx = jnp.mod(jnp.arange(W) - shift, W)
-        inv = jnp.argsort(idx)
-        return {"k": kw[:, inv], "v": vw[:, inv], "kv_pos": pos[inv]}
-    # S < W: token p already belongs at slot p; pad empty slots at the back
-    pos = jnp.concatenate([jnp.arange(S, dtype=jnp.int32),
-                           jnp.full((W - S,), -1, jnp.int32)])
-    kw = jnp.pad(k, ((0, 0), (0, W - S), (0, 0), (0, 0)))
-    vw = jnp.pad(v, ((0, 0), (0, W - S), (0, 0), (0, 0)))
-    return {"k": kw, "v": vw, "kv_pos": pos}
-
-
 def prefill(cfg, params, batch):
-    x, positions = _embed_inputs(cfg, params, batch)
+    x = B.embed(params["embed"], batch["tokens"])
     S = x.shape[1]
-    x, kvs, _aux = _backbone(cfg, params, x, positions, collect_kv=True)
+    x, (k, v) = _backbone(cfg, params, x, jnp.arange(S), collect_kv=True)
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
     logits = B.unembed(params["embed"], x[:, -1:])
-
-    if cfg.attn_pattern == "local_global":
-        ((lk, lv), (gk, gv)), tail_kvs = kvs
-        W = cfg.window
-        local = jax.vmap(jax.vmap(lambda k, v: _ring_cache_from_kv(k, v, S, W)))(lk, lv)
-        full = jax.vmap(lambda k, v: _full_cache_from_kv(k, v, S))(gk, gv)
-        cache = {"pos": jnp.int32(S), "local": local, "full": full}
-        if tail_kvs is not None:
-            tk, tv = tail_kvs
-            cache["tail"] = jax.vmap(
-                lambda k, v: _ring_cache_from_kv(k, v, S, W))(tk, tv)
-    else:
-        k, v = kvs
-        if cfg.attn_pattern == "swa":
-            W = cfg.window
-            cache = {"pos": jnp.int32(S),
-                     "win": jax.vmap(lambda kk, vv: _ring_cache_from_kv(kk, vv, S, W))(k, v)}
-        else:
-            cache = {"pos": jnp.int32(S),
-                     "full": jax.vmap(lambda kk, vv: _full_cache_from_kv(kk, vv, S))(k, v)}
-    return logits, cache
+    return logits, {"pos": jnp.int32(S),
+                    "full": jax.vmap(lambda kk, vv: _full_cache_from_kv(kk, vv, S))(k, v)}
 
 
 def init_cache(cfg, batch_size: int, seq_len: int):
-    """Empty cache with capacity for seq_len history (+pad) — what serve_step
-    is lowered against in the dry-run."""
+    """Empty cache with capacity for seq_len history (+pad)."""
     dt = B.dtype_of(cfg)
     KV, hd = cfg.n_kv_heads, cfg.head_dim
-    C = seq_len + CACHE_PAD
-
-    def full(n):
-        c = {"k": jnp.zeros((n, batch_size, C, KV, hd),
-                            jnp.int8 if cfg.cache_quant else dt),
-             "v": jnp.zeros((n, batch_size, C, KV, hd),
-                            jnp.int8 if cfg.cache_quant else dt),
-             "kv_pos": jnp.full((n, C), -1, jnp.int32)}
-        if cfg.cache_quant:
-            c["k_scale"] = jnp.zeros((n, batch_size, C, KV, 1), jnp.bfloat16)
-            c["v_scale"] = jnp.zeros((n, batch_size, C, KV, 1), jnp.bfloat16)
-        return c
-
-    def ring(shape_prefix):
-        W = cfg.window
-        return {"k": jnp.zeros(shape_prefix + (batch_size, W, KV, hd), dt),
-                "v": jnp.zeros(shape_prefix + (batch_size, W, KV, hd), dt),
-                "kv_pos": jnp.full(shape_prefix + (W,), -1, jnp.int32)}
-
-    pos = jnp.int32(seq_len)
-    if cfg.attn_pattern == "local_global":
-        g = cfg.local_per_global + 1
-        G = cfg.n_layers // g
-        rem = cfg.n_layers - G * g
-        cache = {"pos": pos, "local": ring((G, cfg.local_per_global)), "full": full(G)}
-        if rem:
-            cache["tail"] = ring((rem,))
-        return cache
-    if cfg.attn_pattern == "swa":
-        return {"pos": pos, "win": ring((cfg.n_layers,))}
-    return {"pos": pos, "full": full(cfg.n_layers)}
+    L, C = cfg.n_layers, seq_len + CACHE_PAD
+    kv_dt = jnp.int8 if cfg.cache_quant else dt
+    full = {"k": jnp.zeros((L, batch_size, C, KV, hd), kv_dt),
+            "v": jnp.zeros((L, batch_size, C, KV, hd), kv_dt),
+            "kv_pos": jnp.full((L, C), -1, jnp.int32)}
+    if cfg.cache_quant:
+        full["k_scale"] = jnp.zeros((L, batch_size, C, KV, 1), jnp.bfloat16)
+        full["v_scale"] = jnp.zeros((L, batch_size, C, KV, 1), jnp.bfloat16)
+    return {"pos": jnp.int32(seq_len), "full": full}
 
 
 def decode_step(cfg, params, cache, token):
     """token: (B,1) int32 → (logits (B,1,V), new cache)."""
     pos = cache["pos"]
     x = B.embed(params["embed"], token)
-    positions = None  # rope applied inside block_decode at `pos`
 
-    if cfg.attn_pattern == "local_global":
-        def local_body(h, xs):
-            lp, lc = xs
-            h, nc = block_decode(cfg, lp, h, lc, pos, "window")
-            return h, nc
+    def body(h, xs):
+        lp, lc = xs
+        return block_decode(cfg, lp, h, lc, pos)
 
-        def group_body(h, xs):
-            (lp, lc), (gp, gc) = xs
-            h, nlc = B.scan_layers(local_body, h, (lp, lc), unroll=cfg.unroll)
-            h, ngc = block_decode(cfg, gp, h, gc, pos, "full")
-            return h, (nlc, ngc)
-
-        x, (nlocal, nfull) = B.scan_layers(
-            group_body, x,
-            ((params["local_layers"], cache["local"]),
-             (params["global_layers"], cache["full"])), unroll=cfg.unroll)
-        new_cache = {"pos": pos + 1, "local": nlocal, "full": nfull}
-        if "tail_local" in params:
-            x, ntail = B.scan_layers(local_body, x,
-                                     (params["tail_local"], cache["tail"]),
-                                     unroll=cfg.unroll)
-            new_cache["tail"] = ntail
-    else:
-        kind = "window" if cfg.attn_pattern == "swa" else "full"
-        ckey = "win" if kind == "window" else "full"
-
-        def body(h, xs):
-            lp, lc = xs
-            h, nc = block_decode(cfg, lp, h, lc, pos, kind)
-            return h, nc
-
-        x, ncache = B.scan_layers(body, x, (params["layers"], cache[ckey]),
-                                  unroll=cfg.unroll)
-        new_cache = {"pos": pos + 1, ckey: ncache}
-
+    x, ncache = jax.lax.scan(body, x, (params["layers"], cache["full"]))
+    new_cache = {"pos": pos + 1, "full": ncache}
     x = B.apply_norm(params["final_norm"], x, cfg.norm)
     logits = B.unembed(params["embed"], x)
     return logits, new_cache

@@ -1,4 +1,3 @@
-from repro.sharding.rules import (batch_axes, batch_spec, cache_specs,
-                                  param_specs, MeshInfo)
+from repro.sharding.rules import batch_spec, cache_specs, param_specs, MeshInfo
 
-__all__ = ["batch_axes", "batch_spec", "cache_specs", "param_specs", "MeshInfo"]
+__all__ = ["batch_spec", "cache_specs", "param_specs", "MeshInfo"]

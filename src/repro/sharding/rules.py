@@ -2,24 +2,23 @@
 
 Mesh axes: ('pod', 'data', 'model') multi-pod, ('data', 'model') single-pod.
   pod    — pure DP: gradients cross the slow inter-pod links once per step
-  data   — FSDP: the 'embed'-like dimension of every weight shards here, so a
-           mixtral-8x22b train state (141B × 12B/param) fits 256×16 GB chips;
+  data   — FSDP: the 'embed'-like dimension of every weight shards here;
            weights are all-gathered per layer inside the scan (compute/comm
            overlap via the XLA latency-hiding scheduler)
-  model  — TP: heads / d_ff / vocab / d_inner; EP when n_experts divides it
+  model  — TP: heads / d_ff / vocab / d_inner
 
 Batch shards over (pod, data); decode caches shard batch — or, when batch
-can't shard (long_500k has B=1), the cache SEQUENCE dimension shards over
-'data' (sequence parallelism for the KV pages).
+can't shard (B=1), the cache SEQUENCE dimension shards over 'data'
+(sequence parallelism for the KV pages).
 """
 from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Dict, Optional, Tuple
+from typing import Tuple
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import Mesh, PartitionSpec as P
 
 
 @dataclasses.dataclass(frozen=True)
@@ -48,101 +47,31 @@ class MeshInfo:
         return self.mesh.shape["data"]
 
 
-# --------------------------------------------------------------- activations
-# Batch-dim sharding constraints for activations (MaxText-style): GSPMD can
-# lose the batch sharding through gathers (embedding lookups), silently
-# replicating (B,S,d) activations across the data axis.  Models call
-# constrain_batch() at block boundaries; it is a no-op unless the launcher
-# declared the activation batch axes for the current mesh.
-_ACTIVATION_BATCH_AXES: Optional[Tuple[str, ...]] = None
-_ACTIVATION_SEQ_AXIS: Optional[Tuple[str, int]] = None  # (axis name, size)
-
-
-def set_activation_batch_axes(axes: Optional[Tuple[str, ...]]) -> None:
-    global _ACTIVATION_BATCH_AXES
-    _ACTIVATION_BATCH_AXES = tuple(axes) if axes else None
-
-
-def set_activation_seq_axis(axis: Optional[str], size: int = 0) -> None:
-    """Megatron-style sequence parallelism for the residual stream: (B,S,d)
-    activations at block boundaries additionally shard S over the TP axis, so
-    the per-layer scan carry saved for backward is 1/tp_size the size.  GSPMD
-    re-gathers at the qkv/mlp projections (all-gather) and scatters after
-    (reduce-scatter) — same wire bytes as the all-reduce it replaces."""
-    global _ACTIVATION_SEQ_AXIS
-    _ACTIVATION_SEQ_AXIS = (axis, size) if axis else None
-
-
-def constrain_batch_only(x):
-    """Pin dim0 to (pod,data) and force every other dim replicated.  Used at
-    the MoE expert-FFN boundary: the dispatched activations must NOT carry the
-    sequence's 'model' sharding, or it conflicts with the expert weights'
-    TP-sharded d_ff and GSPMD falls back to fully replicating the experts."""
-    if _ACTIVATION_BATCH_AXES is None or x.ndim < 2:
-        return x
-    spec = P(_ACTIVATION_BATCH_AXES, *([None] * (x.ndim - 1)))
-    return jax.lax.with_sharding_constraint(x, spec)
-
-
-def constrain_batch(x):
-    """Pin dim0 of an activation to (pod, data); optionally dim1 to the TP
-    axis (sequence parallelism) when divisible."""
-    if _ACTIVATION_BATCH_AXES is None or x.ndim < 2:
-        return x
-    rest = [None] * (x.ndim - 1)
-    if (_ACTIVATION_SEQ_AXIS is not None and x.ndim == 3
-            and x.shape[1] % max(_ACTIVATION_SEQ_AXIS[1], 1) == 0
-            and x.shape[1] >= _ACTIVATION_SEQ_AXIS[1]):
-        rest[0] = _ACTIVATION_SEQ_AXIS[0]
-    spec = P(_ACTIVATION_BATCH_AXES, *rest)
-    return jax.lax.with_sharding_constraint(x, spec)
-
-
-# Sharding policy: 'tp' (default — TP over 'model', FSDP over 'data') or
-# 'dp' (pure data parallel + FSDP over BOTH axes: right for small models whose
-# TP collectives would dwarf their compute — see EXPERIMENTS.md §Perf).
-_POLICY = "tp"
-
-
-def set_policy(policy: str) -> None:
-    global _POLICY
-    assert policy in ("tp", "dp", "serve")
-    _POLICY = policy
-
-
-def get_policy() -> str:
-    return _POLICY
-
-
-# (regex, base_rank, trailing spec) — leading stacked-layer dims are padded
-# with None.  Trailing spec axes: F = fsdp('data'), T = tp('model').
+# (regex, trailing spec) — leading stacked-layer dims are padded with None.
+# Trailing spec axes: F = fsdp('data'), T = tp('model'); () replicates.
 F, T = "data", "model"
 _RULES = [
-    (r"embed/table$",        2, (T, F)),
-    (r"embed/unembed$",      2, (F, T)),
-    (r"dec_pos$",            2, (None, F)),
-    (r"attn/w[qkv]$",        2, (F, T)),
-    (r"attn/wo$",            2, (T, F)),
-    (r"mlp/w[gi]$",          2, (F, T)),
-    (r"mlp/wo$",             2, (T, F)),
-    (r"moe/router$",         2, (F, None)),
-    (r"moe/w[gi]$",          3, "MOE_IN"),
-    (r"moe/wo$",             3, "MOE_OUT"),
-    (r"ssm/in_proj$",        2, (F, T)),
-    (r"ssm/out_proj$",       2, (T, F)),
-    (r"ssm/conv_w$",         2, (None, T)),
-    (r"ssm/conv_b$",         1, (T,)),
-    (r"ssm/(A_log|D|dt_bias)$", 1, (None,)),
-    (r"ssm/gate_norm$",      1, (T,)),
-    (r"tm/w[rkvg]$",         2, (F, T)),
-    (r"tm/wo$",              2, (T, F)),
-    (r"tm/w_lora_a$",        2, (F, None)),
-    (r"tm/w_lora_b$",        2, (None, T)),
-    (r"tm/(mu|w0|u|ln)$",    0, "REPL"),
-    (r"cm/w[rk]$",           2, (F, T)),
-    (r"cm/wv$",              2, (T, F)),
-    (r"cm/mu$",              0, "REPL"),
-    (r"(ln1|ln2|ln_x|ln_in|ln|final_norm|enc_norm|gate_norm)(/scale)?$", 0, "REPL"),
+    (r"embed/table$",        (T, F)),
+    (r"embed/unembed$",      (F, T)),
+    (r"attn/w[qkv]$",        (F, T)),
+    (r"attn/wo$",            (T, F)),
+    (r"mlp/w[gi]$",          (F, T)),
+    (r"mlp/wo$",             (T, F)),
+    (r"ssm/in_proj$",        (F, T)),
+    (r"ssm/out_proj$",       (T, F)),
+    (r"ssm/conv_w$",         (None, T)),
+    (r"ssm/conv_b$",         (T,)),
+    (r"ssm/(A_log|D|dt_bias)$", (None,)),
+    (r"ssm/gate_norm$",      (T,)),
+    (r"tm/w[rkvg]$",         (F, T)),
+    (r"tm/wo$",              (T, F)),
+    (r"tm/w_lora_a$",        (F, None)),
+    (r"tm/w_lora_b$",        (None, T)),
+    (r"tm/(mu|w0|u|ln)$",    ()),
+    (r"cm/w[rk]$",           (F, T)),
+    (r"cm/wv$",              (T, F)),
+    (r"cm/mu$",              ()),
+    (r"(ln1|ln2|ln_in|ln|final_norm)(/scale)?$", ()),
 ]
 
 
@@ -156,70 +85,31 @@ def _path_str(path) -> str:
     return "/".join(parts)
 
 
-def spec_for_param(path: str, shape: Tuple[int, ...], info: MeshInfo,
-                   n_experts: int = 0) -> P:
-    for regex, base_rank, trailing in _RULES:
+def spec_for_param(path: str, shape: Tuple[int, ...], info: MeshInfo) -> P:
+    for regex, trailing in _RULES:
         if re.search(regex, path):
-            if trailing == "REPL":
+            if not trailing:
                 return P()
-            if trailing == "MOE_IN":      # (E, d, f)
-                if n_experts and n_experts % info.model_size == 0:
-                    trailing = (T, F, None)       # true EP
-                else:
-                    trailing = (None, F, T)       # TP-MoE
-            elif trailing == "MOE_OUT":   # (E, f, d)
-                if n_experts and n_experts % info.model_size == 0:
-                    trailing = (T, None, F)
-                else:
-                    trailing = (None, T, F)
             lead = len(shape) - len(trailing)
             spec = (None,) * lead + tuple(trailing)
-            if _POLICY == "dp":
-                # fold TP away; FSDP over the merged (data, model) axes
-                spec = tuple(("data", "model") if ax == F else
-                             (None if ax == T else ax) for ax in spec)
-            elif _POLICY == "serve":
-                # replicate params over 'data' (no per-layer FSDP gathers on
-                # the decode path); TP over 'model' carries the weights
-                spec = tuple(None if ax == F else ax for ax in spec)
             # drop shardings that don't divide (robustness for reduced configs)
-            fixed = []
-            for dim, ax in zip(shape, spec):
-                if ax == ("data", "model"):
-                    size = info.fsdp_size * info.model_size
-                elif ax in (F, T):
-                    size = {F: info.fsdp_size, T: info.model_size}.get(ax, 1)
-                else:
-                    size = 1
-                fixed.append(ax if ax and dim % size == 0 and dim >= size else None)
-            return P(*fixed)
+            size = {F: info.fsdp_size, T: info.model_size}
+            return P(*(ax if ax and dim % size[ax] == 0 and dim >= size[ax]
+                       else None for dim, ax in zip(shape, spec)))
     return P()  # default: replicate
 
 
-def param_specs(params, info: MeshInfo, n_experts: int = 0):
+def param_specs(params, info: MeshInfo):
     """Pytree of PartitionSpec matching `params` (arrays or ShapeDtypeStructs)."""
     def one(path, leaf):
-        return spec_for_param(_path_str(path), leaf.shape, info, n_experts)
+        return spec_for_param(_path_str(path), leaf.shape, info)
     return jax.tree_util.tree_map_with_path(one, params)
 
 
-def batch_axes(info: MeshInfo):
-    if _POLICY == "dp":
-        return info.data_axes + ("model",)
-    return info.data_axes
-
-
 def batch_spec(batch, info: MeshInfo):
-    """tokens/frames/patches: shard the leading batch dim over (pod, data)
-    (+ 'model' under the dp policy)."""
-    da = batch_axes(info)
-    dsz = info.data_size * (info.model_size if _POLICY == "dp" else 1)
-
+    """tokens: shard the leading batch dim over (pod, data)."""
     def one(leaf):
-        b = leaf.shape[0]
-        if b % dsz == 0:
-            return P(da, *([None] * (len(leaf.shape) - 1)))
-        if b % info.data_size == 0:
+        if leaf.shape[0] % info.data_size == 0:
             return P(info.data_axes, *([None] * (len(leaf.shape) - 1)))
         return P(*([None] * len(leaf.shape)))
     return jax.tree.map(one, batch)
@@ -227,14 +117,13 @@ def batch_spec(batch, info: MeshInfo):
 
 def cache_specs(cache, info: MeshInfo, *, batch_size: int):
     """Decode caches: shard batch over (pod,data) when divisible; otherwise
-    (long_500k, B=1) shard the big sequence/capacity dimension over 'data'
-    (sequence parallelism), heads over 'model'."""
+    (B=1) shard the big sequence/capacity dimension over 'data' (sequence
+    parallelism), heads over 'model'."""
     da = info.data_axes
     batch_ok = batch_size % info.data_size == 0
 
     def one(path, leaf):
         shape = leaf.shape
-        name = _path_str(path)
         if leaf.dtype.name.startswith("int") and len(shape) <= 2:
             # kv_pos (L, C): shard C over data in seq-parallel mode
             if not batch_ok and len(shape) == 2 and shape[1] % info.fsdp_size == 0:

@@ -1,3 +1,3 @@
-from repro.train.step import TrainState, make_train_state_abstract, make_train_step
+from repro.train.step import TrainState, make_train_step
 
-__all__ = ["TrainState", "make_train_state_abstract", "make_train_step"]
+__all__ = ["TrainState", "make_train_step"]

@@ -1,5 +1,4 @@
-"""Training step factory: value_and_grad over the model loss + AdamW update,
-with optional gradient accumulation (microbatching) and donated train state.
+"""Training step factory: value_and_grad over the model loss + AdamW update.
 
 The returned step is pjit-ready: callers pass in_shardings built from
 sharding.rules; parameters FSDP+TP shard, moments follow parameters (ZeRO-1),
@@ -7,14 +6,12 @@ gradients reduce over (pod, data) implicitly via GSPMD.
 """
 from __future__ import annotations
 
-import dataclasses
-import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-from repro.optim import AdamWConfig, adamw_init, adamw_update, cosine_schedule
+from repro.optim import AdamWConfig, adamw_init, adamw_update
 
 TrainState = Dict[str, Any]  # {"params": ..., "opt": {m, v, step}}
 
@@ -24,45 +21,13 @@ def make_train_state(model, key, max_seq: int = 4096) -> TrainState:
     return {"params": params, "opt": adamw_init(params)}
 
 
-def make_train_state_abstract(model, max_seq: int = 4096) -> TrainState:
-    return jax.eval_shape(
-        lambda: make_train_state(model, jax.random.PRNGKey(0), max_seq))
-
-
 def make_train_step(model, opt_cfg: AdamWConfig = AdamWConfig(),
-                    *, n_microbatches: int = 1,
-                    unroll_micro: bool = False,
-                    schedule: Optional[Callable] = None):
+                    *, schedule: Optional[Callable] = None):
     loss_fn = model.train_loss
 
     def step(state: TrainState, batch) -> Tuple[TrainState, Dict[str, jnp.ndarray]]:
         params = state["params"]
-        if n_microbatches == 1:
-            loss, grads = jax.value_and_grad(loss_fn)(params, batch)
-        else:
-            def split(x):
-                b = x.shape[0]
-                return x.reshape(n_microbatches, b // n_microbatches, *x.shape[1:])
-            micro = jax.tree.map(split, batch)
-
-            def acc_body(carry, mb):
-                l, g = jax.value_and_grad(loss_fn)(params, mb)
-                acc_l, acc_g = carry
-                return (acc_l + l, jax.tree.map(jnp.add, acc_g, g)), None
-
-            zero_g = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-            init = (jnp.float32(0.0), zero_g)
-            if unroll_micro:  # measurement mode: expose the trip count
-                carry = init
-                for i in range(n_microbatches):
-                    carry, _ = acc_body(carry, jax.tree.map(lambda a: a[i], micro))
-                loss, grads = carry
-            else:
-                (loss, grads), _ = jax.lax.scan(acc_body, init, micro)
-            inv = 1.0 / n_microbatches
-            loss = loss * inv
-            grads = jax.tree.map(lambda g: g * inv, grads)
-
+        loss, grads = jax.value_and_grad(loss_fn)(params, batch)
         lr_scale = schedule(state["opt"]["step"]) if schedule else 1.0
         new_params, new_opt, metrics = adamw_update(
             opt_cfg, params, grads, state["opt"], lr_scale)

@@ -1,6 +1,5 @@
 """Erda-protocol checkpointing: atomic commit, torn-write fallback, restart,
 elastic resharding, straggler semantics."""
-import os
 
 import jax
 import jax.numpy as jnp
@@ -117,9 +116,20 @@ def test_training_restart_resumes(tmp_path):
 
 
 def test_elastic_reshard_restore():
-    os.environ.setdefault("XLA_FLAGS", "")
-    if jax.device_count() < 2:
-        pytest.skip("needs >1 host device (run via test_dryrun_small)")
+    """The newest checkpoint restores onto a mesh, sharded by the rules."""
+    from jax.sharding import Mesh
+    from repro.launch.elastic import reshard_restore
+    from repro.optim import adamw_init
+    params = small_state()["params"]
+    state = {"params": params, "opt": adamw_init(params)}
+    mgr = small_mgr()
+    mgr.save(10, state)
+    mesh = Mesh(np.array(jax.devices()).reshape(-1, 1), ("data", "model"))
+    step, got = reshard_restore(mgr, state, mesh)
+    assert step == 10
+    assert_state_equal(state, got)
+    for leaf in jax.tree.leaves(got["params"]):
+        assert leaf.sharding.mesh.shape == mesh.shape
 
 
 def test_straggler_never_blocks_readers():

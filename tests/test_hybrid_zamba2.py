@@ -222,7 +222,7 @@ def _tree_size(cfg) -> int:
 
 
 @pytest.mark.parametrize("name,n_layers", [("zamba2_7b", 12), ("zamba2_7b", None),
-                                           ("zamba2_1p2b", None), ("tiny", None)])
+                                           ("tiny", None)])
 def test_param_count_is_the_program_tree(name, n_layers):
     cfg = tiny() if name == "tiny" else get_config(name)
     if n_layers:

@@ -11,7 +11,6 @@ from repro.configs import get_config
 from repro.models.layers import attention as A
 from repro.models.layers import rwkv as R
 from repro.models.layers import ssm as S
-from repro.models.layers.moe import apply_moe, capacity, init_moe
 
 pytestmark = pytest.mark.slow  # JAX model/train lane; excluded from tier-1
 
@@ -30,27 +29,8 @@ def test_chunked_attention_matches_dense(S_, H, KV, hd, chunk):
     q = jnp.asarray(rng.standard_normal((2, S_, H, hd)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((2, S_, KV, hd)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((2, S_, KV, hd)), jnp.float32)
-    got = A.chunked_attention(q, k, v, cfg, causal=True)
-    want = A.full_attention(q, k, v, causal=True)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
-
-
-@pytest.mark.parametrize("S_,W,chunk", [(128, 32, 32), (256, 64, 64), (128, 64, 32)])
-def test_banded_attention_matches_masked_dense(S_, W, chunk):
-    cfg = dataclasses.replace(f32cfg("mixtral_8x22b"), attn_chunk=chunk, window=W)
-    rng = np.random.default_rng(1)
-    H, KV, hd = 4, 2, 16
-    q = jnp.asarray(rng.standard_normal((2, S_, H, hd)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((2, S_, KV, hd)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((2, S_, KV, hd)), jnp.float32)
-    got = A.banded_attention(q, k, v, cfg, window=W)
-    # dense reference with the SWA mask
-    qg = q.reshape(2, S_, KV, H // KV, hd) / np.sqrt(hd)
-    s = jnp.einsum("bqkgh,bckh->bqkgc", qg, k)
-    i = jnp.arange(S_)
-    mask = (i[:, None] >= i[None, :]) & (i[:, None] - i[None, :] < W)
-    s = jnp.where(mask[None, :, None, None, :], s, -1e30)
-    want = jnp.einsum("bqkgc,bckh->bqkgh", jax.nn.softmax(s, -1), v).reshape(2, S_, H, hd)
+    got = A.chunked_attention(q, k, v, cfg)
+    want = A.full_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
@@ -63,7 +43,7 @@ def test_decode_attention_matches_last_row_of_dense():
     kv_pos = jnp.arange(S_)
     got = A.decode_attention(q, k, v, kv_pos, S_ - 1)
     qf = jnp.concatenate([jnp.zeros((B, S_ - 1, H, hd)), q], axis=1)
-    want = A.full_attention(qf, k, v, causal=True)[:, -1:]
+    want = A.full_attention(qf, k, v)[:, -1:]
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=2e-4, atol=2e-4)
 
 
@@ -85,7 +65,7 @@ def ssm_sequential_ref(x, B_in, C_in, dt, A_, D):
 
 @pytest.mark.parametrize("S_,chunk", [(32, 8), (64, 16), (48, 16)])
 def test_ssm_chunked_matches_sequential(S_, chunk):
-    cfg = dataclasses.replace(f32cfg("zamba2_1p2b"), ssm_chunk=chunk)
+    cfg = dataclasses.replace(f32cfg("zamba2_7b"), ssm_chunk=chunk)
     rng = np.random.default_rng(3)
     Bsz, nh, hp, ds = 2, 4, 8, 16
     x = jnp.asarray(rng.standard_normal((Bsz, S_, nh, hp)), jnp.float32)
@@ -102,7 +82,7 @@ def test_ssm_chunked_matches_sequential(S_, chunk):
 
 def test_ssm_decode_continues_chunked():
     """State handoff: chunked(S) then decode(1) ≡ chunked(S+1)."""
-    cfg = f32cfg("zamba2_1p2b")
+    cfg = f32cfg("zamba2_7b")
     model_cfg = dataclasses.replace(cfg, ssm_chunk=8)
     rng = np.random.default_rng(4)
     key = jax.random.PRNGKey(0)
@@ -158,40 +138,3 @@ def test_wkv_state_handoff():
     np.testing.assert_allclose(np.asarray(jnp.concatenate([y1, y2], 1)),
                                np.asarray(y_full), rtol=1e-4, atol=1e-4)
     np.testing.assert_allclose(np.asarray(h2), np.asarray(h_full), rtol=1e-4, atol=1e-4)
-
-
-# ------------------------------------------------------------------------- moe
-def test_moe_capacity_combines_topk():
-    cfg = f32cfg("mixtral_8x22b")
-    key = jax.random.PRNGKey(0)
-    p = init_moe(cfg, key)
-    x = jnp.asarray(np.random.default_rng(7).standard_normal((2, 16, cfg.d_model)),
-                    jnp.float32) * 0.3
-    y = apply_moe(p, x, cfg)
-    assert y.shape == x.shape
-    assert np.all(np.isfinite(np.asarray(y)))
-    # with ample capacity, output must equal the explicit top-k mixture
-    big = dataclasses.replace(cfg, capacity_factor=8.0)
-    y_big = apply_moe(p, x, big)
-    logits = x @ p["router"]
-    gates = jax.nn.softmax(logits, -1)
-    topv, topi = jax.lax.top_k(gates, cfg.n_experts_active)
-    topv = topv / topv.sum(-1, keepdims=True)
-    want = np.zeros_like(np.asarray(x))
-    act = jax.nn.silu
-    for b in range(2):
-        for s in range(16):
-            acc = np.zeros(cfg.d_model)
-            for j in range(cfg.n_experts_active):
-                e = int(topi[b, s, j])
-                xe = np.asarray(x[b, s])
-                h = np.asarray(act(xe @ p["wg"][e])) * np.asarray(xe @ p["wi"][e])
-                acc += float(topv[b, s, j]) * (h @ np.asarray(p["wo"][e]))
-            want[b, s] = acc
-    np.testing.assert_allclose(np.asarray(y_big), want, rtol=2e-3, atol=2e-3)
-
-
-def test_moe_capacity_value():
-    cfg = f32cfg("mixtral_8x22b")
-    assert capacity(cfg, 1) >= 1
-    assert capacity(cfg, 1024) <= 1024

@@ -51,7 +51,11 @@ def check_matches_dict_model(store, ops):
 def check_torn_write_invariant(store, dev, ops, tear_at, fraction):
     """THE paper invariant: inject one torn data write anywhere in an op
     stream; every subsequent read returns either the pre-tear value or a
-    post-tear written value — never garbage, never a partial object."""
+    post-tear written value — never garbage, never a partial object.
+
+    A tear whose unwritten tail already held the record's bytes leaves the
+    whole record on NVM (say, a tail of zeros over zeroed NVM): its CRC
+    passes and the new value is the one read."""
     model = {}
     writes_seen = 0
     for op, k, v in ops:
@@ -61,8 +65,12 @@ def check_torn_write_invariant(store, dev, ops, tear_at, fraction):
                 try:
                     store.write(k, v)
                     model[k] = v  # tear hit a different (e.g. metadata) spot
-                except TornWrite:
-                    pass  # model keeps the OLD value for k
+                except TornWrite as e:
+                    rec = layout.pack_record(k, v)
+                    on_nvm = bytes(dev.mem[e.addr : e.addr + e.requested])
+                    if e.requested == len(rec) and on_nvm == rec:
+                        model[k] = v  # the record is whole after all
+                    # otherwise the model keeps the OLD value for k
                 writes_seen += 1
                 continue
             writes_seen += 1
@@ -297,6 +305,15 @@ def test_smoke_torn_write_never_corrupts_observable_state():
         tear_at = int(rng.integers(0, 31))
         fraction = float(rng.random() * 0.95)
         check_torn_write_invariant(s, s.dev, ops, tear_at, fraction)
+
+
+def test_torn_write_that_leaves_the_record_whole_reads_the_new_value():
+    """The tear persists 3/4 of the third record; its unwritten tail is zeros
+    over zeroed NVM, so the record is whole and the new value is read."""
+    s = small_store()
+    ops = [("write", 1, b""), ("write", 1, b""), ("write", 1, b"\x00")]
+    check_torn_write_invariant(s, s.dev, ops, 2, 0.75)
+    assert s.read(1) == b"\x00"
 
 
 def test_smoke_cleaning_idempotent_contents():

@@ -1,4 +1,4 @@
-"""Compile the Pallas kernels for a described TPU v5e, without the chip.
+"""Compile the Pallas kernel for a described TPU v5e, without the chip.
 
 The TPU compiler is installed with jax; it compiles for a chip that is
 described and not attached.  Nothing runs, so these tests say nothing about
@@ -14,7 +14,6 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.crc32 import crc32_pallas
-from repro.kernels.flash_attention import flash_attention_pallas
 
 
 @pytest.fixture(scope="module")
@@ -56,9 +55,3 @@ def test_crc32_kernel_compiles_for_v5e(one_chip, no_compile_cache, n, w):
     data = jax.ShapeDtypeStruct((n, w), jnp.uint32, sharding=one_chip)
     _compile(crc32_pallas, data)
 
-
-def test_flash_attention_compiles_for_v5e_at_olmo_1b_widths(
-        one_chip, no_compile_cache):
-    # olmo_1b: 16 heads × head_dim 128; batch 4 → 64 (batch·head) rows
-    qkv = jax.ShapeDtypeStruct((64, 2048, 128), jnp.bfloat16, sharding=one_chip)
-    _compile(flash_attention_pallas, qkv, qkv, qkv)
